@@ -62,6 +62,14 @@ TRAIN_SCRIPT = textwrap.dedent(
 )
 
 
+def _child_env() -> dict:
+    """Environment of a child that simulates 8 chips on virtual host
+    devices: pinned to the CPU, never inheriting the parent's platform."""
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    return env
+
+
 def measure_detection(reps: int = 3) -> dict:
     from repro.launch.rendezvous import run_elastic_ring
 
@@ -81,9 +89,7 @@ def measure_detection(reps: int = 3) -> dict:
 
 def measure_recovery() -> dict:
     root = os.path.join(os.path.dirname(__file__), "..")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("XLA_FLAGS", None)
+    env = _child_env()
     out: dict = {}
     for mode in ("live", "restore"):
         with tempfile.TemporaryDirectory() as ckdir:
@@ -108,17 +114,16 @@ BIG_STATE_SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax
     import jax.numpy as jnp
-    import numpy as np
     from repro.checkpoint import CheckpointManager
     from repro.dist.fault import remesh_plan
+    from repro.launch.mesh import make_mesh
 
     mib = int(sys.argv[2])
     # a pytree of float32 shards totalling >= mib MiB, sharded over 'data'
     n_arrays = 8
     rows = (mib * (1 << 20)) // (4 * 1024 * n_arrays)
     def mesh_for(plan):
-        devs = np.array(jax.devices()[: plan.n_chips]).reshape(plan.shape)
-        return jax.sharding.Mesh(devs, plan.axes)
+        return make_mesh(plan.shape, plan.axes, devices=jax.devices()[: plan.n_chips])
     def shardings(mesh):
         spec = jax.sharding.PartitionSpec("data", None)
         return {f"w{i}": jax.sharding.NamedSharding(mesh, spec)
@@ -168,9 +173,7 @@ BIG_STATE_SCRIPT = textwrap.dedent(
 
 def measure_big_state(mib: int = 64) -> dict:
     root = os.path.join(os.path.dirname(__file__), "..")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("XLA_FLAGS", None)
+    env = _child_env()
     with tempfile.TemporaryDirectory() as ckdir:
         r = subprocess.run(
             [sys.executable, "-c", BIG_STATE_SCRIPT, ckdir, str(mib)],

@@ -95,7 +95,7 @@ def run_probes(only_arch=None, only_shape=None) -> None:
                 "--set", "probe_unroll=true",
             ]
             print(f"[probe] {arch} {shape.name} depth={depth}", flush=True)
-            env = dict(os.environ, PYTHONPATH="src")
+            env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
             r = subprocess.run(cmd, env=env, capture_output=True, text=True)
             if r.returncode != 0:
                 print(r.stdout[-2000:], r.stderr[-2000:], flush=True)
@@ -237,7 +237,7 @@ def probe_cell(arch: str, shape_name: str, overrides: dict, tag: str) -> None:
                "--shape", shape_name, "--single-pod", "--tag", t]
         for kv in base_sets + extra:
             cmd += ["--set", kv]
-        env = dict(os.environ, PYTHONPATH="src")
+        env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
         r = subprocess.run(cmd, env=env, capture_output=True, text=True)
         if r.returncode != 0:
             print(r.stdout[-1500:], r.stderr[-1500:], flush=True)

@@ -551,7 +551,7 @@ class RetryingTransport(SpTransport):
 @dataclass(frozen=True)
 class RemeshPlan:
     """A shrunken mesh layout: build it with
-    ``jax.sharding.Mesh(devices[:n_chips].reshape(shape), axes)``."""
+    ``repro.launch.mesh.make_mesh(shape, axes, devices=devices[:n_chips])``."""
 
     shape: tuple[int, ...]
     axes: tuple[str, ...]

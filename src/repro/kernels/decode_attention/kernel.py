@@ -21,11 +21,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -103,9 +99,9 @@ def decode_attention_pallas(
 
     kernel = functools.partial(_decode_kernel, scale=scale, bs=bs, ns=ns)
     scratch_shapes = [
-        pltpu.VMEM((G, 1), jnp.float32) if pltpu else jax.ShapeDtypeStruct((G, 1), jnp.float32),
-        pltpu.VMEM((G, 1), jnp.float32) if pltpu else jax.ShapeDtypeStruct((G, 1), jnp.float32),
-        pltpu.VMEM((G, Dv), jnp.float32) if pltpu else jax.ShapeDtypeStruct((G, Dv), jnp.float32),
+        pltpu.VMEM((G, 1), jnp.float32),
+        pltpu.VMEM((G, 1), jnp.float32),
+        pltpu.VMEM((G, Dv), jnp.float32),
     ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -27,14 +27,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; interpret mode works without them
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -151,24 +144,11 @@ def flash_attention_pallas(
         nk=nk,
         q_offset=q_offset,
     )
-    scratch = [
-        jax.ShapeDtypeStruct((bq, 1), jnp.float32),
-        jax.ShapeDtypeStruct((bq, 1), jnp.float32),
-        jax.ShapeDtypeStruct((bq, Dv), jnp.float32),
+    scratch_shapes = [
+        pltpu.VMEM((bq, 1), jnp.float32),
+        pltpu.VMEM((bq, 1), jnp.float32),
+        pltpu.VMEM((bq, Dv), jnp.float32),
     ]
-    if _VMEM is not None and not interpret:
-        scratch_shapes = [
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, Dv), jnp.float32),
-        ]
-    else:
-        scratch_shapes = [
-            pltpu.VMEM((bq, 1), jnp.float32) if pltpu else jax.ShapeDtypeStruct((bq, 1), jnp.float32)
-            for _ in range(2)
-        ] + [
-            pltpu.VMEM((bq, Dv), jnp.float32) if pltpu else jax.ShapeDtypeStruct((bq, Dv), jnp.float32)
-        ]
 
     out = pl.pallas_call(
         kernel,

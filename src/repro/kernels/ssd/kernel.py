@@ -23,11 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
 
 def _ssd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, state_ref, *, cs: int):
     x = x_ref[0, 0].astype(jnp.float32)    # (cs, P)

@@ -1,5 +1,8 @@
 import os
 
+# 512 placeholder chips exist only as virtual host devices: pin the CPU
+# platform so this never takes (or waits for) an accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")

@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import ServeEngine, shrunken_draft
 
@@ -55,6 +56,7 @@ def main(argv=None) -> dict:
         help="number of target layers kept in the shrunken draft model",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     params = init_params(jax.random.PRNGKey(0), cfg)

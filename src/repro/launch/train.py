@@ -45,7 +45,8 @@ from repro.core import SpRankDeadError, SpRuntime
 from repro.data import Prefetcher, SyntheticLMDataset
 from repro.dist.fault import FailureSimulator, remesh_plan
 from repro.dist.sharding import use_mesh
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh, make_mesh
 from repro.models.config import ShapeSpec
 from repro.optim import linear_warmup_cosine
 from repro.runtime.train import (
@@ -73,6 +74,10 @@ def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-7b")
     ap.add_argument("--reduced", action="store_true", help="smoke-scale config")
+    ap.add_argument(
+        "--layers", type=int, default=None,
+        help="cut the config's depth to this many layers (widths unchanged)",
+    )
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -97,14 +102,19 @@ def main(argv=None) -> dict:
         help="write recovery timings as JSON to PATH",
     )
     args = ap.parse_args(argv)
+    n_devices = len(jax.devices())
+    if args.fail_at is not None and n_devices < 2:
+        ap.error(f"--fail-at needs several devices to re-mesh over; found {n_devices}")
+    enable_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
+    if args.layers is not None:
+        cfg = cfg.replace(n_layers=args.layers)
     shape = ShapeSpec("train", "train", args.seq, args.batch)
     ds = SyntheticLMDataset(cfg, shape, seed=0)
     mgr = CheckpointManager(args.ckpt_dir, keep=3) if args.ckpt_dir else None
     sim = args.fail_at
 
-    n_devices = len(jax.devices())
     mesh = make_host_mesh() if n_devices > 1 else None
     lr = linear_warmup_cosine(args.lr, warmup=10, total_steps=args.steps)
 
@@ -133,7 +143,6 @@ def main(argv=None) -> dict:
                 n_microbatches=args.microbatches,
                 schedule_policy=args.schedule_policy,
                 lr_schedule=lr,
-                donate=False,
             )
         if st["pf"] is not None:
             st["pf"].stop()
@@ -146,9 +155,8 @@ def main(argv=None) -> dict:
             start_step, st["state"] = mgr.restore(abstract_train_state(cfg))
             print(f"[train] resumed from step {start_step}")
         else:
+            # under the mesh this initialises straight into the shardings
             st["state"] = init_train_state(jax.random.PRNGKey(0), cfg)
-            if mesh is not None:
-                st["state"] = jax.device_put(st["state"], train_state_shardings(cfg))
     base_step = start_step
     _bind(start_step)
 
@@ -176,9 +184,6 @@ def main(argv=None) -> dict:
             st["restorable"] = True
         if sim is not None:
             failed = sim.check(s)
-            if failed and st["mesh"] is None:
-                print("[train] failure injected but only one device; continuing")
-                failed = 0
             if failed:
                 st["failed_ranks"] = failed
                 raise SpRankDeadError(
@@ -198,8 +203,7 @@ def main(argv=None) -> dict:
             failed_ranks,
             model_parallel=int(st["mesh"].shape["model"]),
         )
-        devices = np.array(jax.devices()[: plan.n_chips]).reshape(plan.shape)
-        st["mesh"] = jax.sharding.Mesh(devices, plan.axes)
+        st["mesh"] = make_mesh(plan.shape, plan.axes, devices=jax.devices()[: plan.n_chips])
         print(
             f"[train] lost {failed_ranks} ranks at step {int(st['state'].step)}; "
             f"re-meshed to {plan.shape} ({plan.dropped_chips} chips dropped)"

@@ -147,11 +147,21 @@ def abstract_train_state(cfg: ArchConfig) -> TrainState:
 
 
 def init_train_state(rng: jax.Array, cfg: ArchConfig) -> TrainState:
+    """Fresh TrainState.  Under an active mesh it is initialised by one
+    jitted program straight into :func:`train_state_shardings`, so no device
+    ever holds the whole state (at published widths that alone can exhaust
+    one chip)."""
     from repro.models import init_params
 
-    params = init_params(rng, cfg)
     opt_init, _ = make_optimizer(cfg.optimizer, cfg.opt_state_dtype)
-    return TrainState(step=jnp.int32(0), params=params, opt=opt_init(params))
+
+    def init(key):
+        params = init_params(key, cfg)
+        return TrainState(step=jnp.int32(0), params=params, opt=opt_init(params))
+
+    if current_mesh() is None:
+        return init(rng)
+    return jax.jit(init, out_shardings=train_state_shardings(cfg))(rng)
 
 
 def build_train_step(

@@ -78,13 +78,14 @@ def _jitted_steps(cfg):
     key = repr(cfg)
     fns = _JIT_CACHE.get(key)
     if fns is None:
-        fns = (
-            jax.jit(
-                lambda p, t, c, pos: decode_step(p, t, c, pos, cfg),
-                donate_argnums=(2,),
-            ),
-            jax.jit(lambda p, b: prefill(p, b, cfg)),
-        )
+
+        def serve_decode(p, t, c, pos):
+            return decode_step(p, t, c, pos, cfg)
+
+        def serve_prefill(p, b):
+            return prefill(p, b, cfg)
+
+        fns = (jax.jit(serve_decode, donate_argnums=(2,)), jax.jit(serve_prefill))
         _JIT_CACHE[key] = fns
     return fns
 
@@ -254,9 +255,12 @@ def _prefill_codelet(out, *, eng, req, sample_first):
     out.value = (primed, first, prompt.shape[1])
 
 
-@sp_task(write=("state",), read=("out",), name="serve.install")
+@sp_task(write=("state", "out"), name="serve.install")
 def _install_codelet(state, out, *, eng, req, slot):
-    primed, first, n_fed = out
+    # take the prefill's output and empty its cell: the graph keeps every
+    # task and its cells, so a filled cell would pin one slot's worth of
+    # KV cache in device memory for each admission the engine ever made
+    (primed, first, n_fed), out.value = out.value, None
     st = state.value
     if first is not None:
         req.out_tokens.append(first)

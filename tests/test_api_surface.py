@@ -71,6 +71,7 @@ def test_quickstart_example_runs():
         env={
             **__import__("os").environ,
             "PYTHONPATH": str(repo / "src"),
+            "JAX_PLATFORMS": "cpu",  # a CPU smoke: never inherit a platform
         },
     )
     assert proc.returncode == 0, proc.stderr[-2000:]

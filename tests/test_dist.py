@@ -24,6 +24,9 @@ from repro.dist.collectives import (
 )
 from repro.dist.fault import CancelToken, FailureSimulator, remesh_plan, run_duplicated
 from repro.dist.sharding import current_mesh, safe_spec, use_mesh
+from repro.configs import reduced_config
+from repro.launch.mesh import SINGLE_POD, SINGLE_POD_AXES, make_host_mesh, make_mesh
+from repro.models import init_params, param_shardings
 
 
 @pytest.fixture()
@@ -238,14 +241,65 @@ def test_remesh_plan_validates():
 
 def test_use_mesh_nests_and_restores():
     assert current_mesh() is None
-    m1 = jax.make_mesh((1, 1), ("data", "model"))
-    m2 = jax.make_mesh((1,), ("data",))
+    m1 = make_mesh((1, 1), ("data", "model"))
+    m2 = make_mesh((1,), ("data",))
     with use_mesh(m1):
         assert current_mesh() is m1
         with use_mesh(m2):
             assert current_mesh() is m2
         assert current_mesh() is m1
     assert current_mesh() is None
+
+
+@pytest.mark.parametrize(
+    "build",
+    [
+        lambda: make_host_mesh(),
+        lambda: make_host_mesh(model_parallel=1),
+        lambda: make_mesh((1,), ("data",)),
+    ],
+    ids=["host", "host_mp1", "one_axis"],
+)
+def test_meshes_have_auto_axes(build):
+    """The sharding model annotates logical axes and relies on propagation:
+    every mesh the repo builds must have Auto axes (``jax.make_mesh``'s
+    default, Explicit, rejects the embedding gather)."""
+    mesh = build()
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * len(mesh.axis_names)
+
+
+def test_production_mesh_has_auto_axes(monkeypatch):
+    """``make_production_mesh`` over 256 (fake) devices: right shape, Auto axes."""
+    import repro.launch.mesh as mesh_mod
+
+    seen = {}
+
+    def fake_make_mesh(shape, axes, axis_types=None, *, devices=None):
+        seen.update(shape=shape, axes=axes, axis_types=axis_types)
+        return None
+
+    monkeypatch.setattr(mesh_mod.jax, "make_mesh", fake_make_mesh)
+    mesh_mod.make_production_mesh()
+    assert seen["shape"] == SINGLE_POD and seen["axes"] == SINGLE_POD_AXES
+    assert seen["axis_types"] == (jax.sharding.AxisType.Auto,) * 2
+    mesh_mod.make_production_mesh(multi_pod=True)
+    assert seen["shape"] == (2, 16, 16)
+    assert seen["axis_types"] == (jax.sharding.AxisType.Auto,) * 3
+
+
+def test_auto_mesh_embedding_gather_partitions():
+    """The failure the Auto axes fix: an embedding gather from a
+    vocab-sharded table under the mesh, which Explicit axes refuse."""
+    from repro.models.layers import embed_apply
+
+    cfg = reduced_config("deepseek-7b")
+    mesh = make_host_mesh()
+    with use_mesh(mesh):
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        params = jax.device_put(params, param_shardings(cfg))
+        tokens = jnp.arange(8, dtype=jnp.int32).reshape(2, 4)
+        out = jax.jit(lambda p, t: embed_apply(p, t, cfg))(params, tokens)
+    assert out.shape == (2, 4, cfg.d_model)
 
 
 def test_safe_spec_uses_each_mesh_axis_once():

@@ -24,6 +24,7 @@ SCRIPT = textwrap.dedent(
     from repro.dist.sharding import use_mesh
     from repro.dist.fault import remesh_plan, FailureSimulator
     from repro.checkpoint import CheckpointManager
+    from repro.launch.mesh import make_host_mesh, make_mesh
     from repro.models.config import ShapeSpec
     from repro.runtime.train import (abstract_train_state, build_train_step,
                                      init_train_state, train_state_shardings)
@@ -35,11 +36,14 @@ SCRIPT = textwrap.dedent(
     mgr = CheckpointManager(ckdir, keep=2, async_commit=False)
     sim = FailureSimulator({4: 4})  # lose half the chips at step 4
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(model_parallel=2)
+    assert dict(mesh.shape) == {"data": 4, "model": 2}
     with use_mesh(mesh):
         sh = train_state_shardings(cfg)
         state = init_train_state(jax.random.PRNGKey(0), cfg)
-        state = jax.device_put(state, sh)
+        # initialised straight into its shardings, not staged on one device
+        placed = jax.tree.map(lambda a, s: a.sharding.is_equivalent_to(s, a.ndim), state, sh)
+        assert all(jax.tree.leaves(placed)), "state not initialised under its shardings"
         art = build_train_step(cfg, n_microbatches=2, donate=False)
         losses = []
         step = 0
@@ -54,8 +58,7 @@ SCRIPT = textwrap.dedent(
     # elastic re-mesh: 8 chips → 4 alive, model_parallel preserved at 2
     plan = remesh_plan(8, 4, model_parallel=2)
     assert plan.shape == (2, 2), plan
-    devices = np.array(jax.devices()[: plan.n_chips]).reshape(plan.shape)
-    mesh2 = jax.sharding.Mesh(devices, plan.axes)
+    mesh2 = make_mesh(plan.shape, plan.axes, devices=jax.devices()[: plan.n_chips])
     with use_mesh(mesh2):
         template = abstract_train_state(cfg)
         restored_step, state2 = mgr.restore(template)
@@ -75,10 +78,20 @@ SCRIPT = textwrap.dedent(
 )
 
 
-def test_elastic_remesh_end_to_end():
+def _child_env() -> dict:
+    """The child simulates its mesh on virtual host devices: pin it to the
+    CPU explicitly rather than inheriting a platform (on a machine with a
+    chip it would otherwise take the chip, or fail behind a parent that
+    holds it)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
+    return env
+
+
+def test_elastic_remesh_end_to_end():
+    env = _child_env()
     r = subprocess.run(
         [sys.executable, "-c", SCRIPT], env=env, capture_output=True, text=True,
         timeout=900, cwd=os.path.join(os.path.dirname(__file__), ".."),
@@ -94,10 +107,11 @@ HIER_SCRIPT = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.dist.collectives import hierarchical_psum
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = make_mesh((2, 4), ("pod", "data"))
     x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16)
 
     @partial(shard_map, mesh=mesh, in_specs=P(("pod", "data"), None),
@@ -121,9 +135,7 @@ HIER_SCRIPT = textwrap.dedent(
 
 
 def test_hierarchical_psum_matches_flat():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("XLA_FLAGS", None)
+    env = _child_env()
     r = subprocess.run(
         [sys.executable, "-c", HIER_SCRIPT], env=env, capture_output=True, text=True,
         timeout=600, cwd=os.path.join(os.path.dirname(__file__), ".."),

@@ -336,3 +336,24 @@ def test_serve_engine_context_manager_closes(served):
     assert eng.closed
     with pytest.raises(RuntimeError):
         eng.submit(p, 2)
+
+
+def test_serve_engine_device_memory_flat_across_waves(served):
+    """Regression: the engine's persistent graph keeps every task and its
+    cells, so a cell left holding a prefill's KV output pinned device memory
+    for every admission the engine ever made; at deepseek-7b widths the
+    engine ran out of chip memory in its second wave.  Live device bytes
+    after a wave must not grow wave over wave."""
+    import gc
+
+    cfg, params = served
+    rng = np.random.default_rng(11)
+    live = []
+    with ServeEngine(cfg, params, n_slots=2, max_seq=32, block_size=4) as eng:
+        for _ in range(3):
+            for _ in range(3):
+                eng.submit(rng.integers(0, cfg.vocab, size=6).astype(np.int32), 5)
+            eng.run_until_drained()
+            gc.collect()
+            live.append(sum(a.nbytes for a in jax.live_arrays()))
+    assert live[2] == live[1], live

@@ -15,6 +15,7 @@ from repro.data import Prefetcher, SyntheticLMDataset
 from repro.dist.collectives import compress_int8, compress_tree, decompress_int8, init_residuals
 from repro.dist.fault import remesh_plan
 from repro.dist.sharding import safe_spec, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.config import ShapeSpec
 from repro.configs import reduced_config
 from repro.optim import (
@@ -182,7 +183,7 @@ def test_checkpoint_async_and_crash_tmp_cleanup(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_safe_spec_drops_indivisible_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with use_mesh(mesh):
         spec = safe_spec((8, 40), ("batch", "heads"))
         assert spec == jax.sharding.PartitionSpec(None, None) or all(

@@ -251,3 +251,13 @@ def test_two_process_ring_all_reduce_over_tcp():
         assert st["boxes"] == 0 and st["queued"] == 0
         assert st["received"] == st["delivered"] > 0
     np.testing.assert_array_equal(results[0]["sum"], results[1]["sum"])
+
+
+def test_spawned_ranks_never_initialise_a_jax_backend(monkeypatch):
+    """Rank processes import JAX but must never initialise a backend: on a
+    machine with a chip, a child that did would take the chip from (or hang
+    behind) the parent that holds it.  Under a platform that cannot
+    initialise, any backend use in a rank would kill it."""
+    monkeypatch.setenv("JAX_PLATFORMS", "no_such_platform")
+    results = run_ring_reduce(2, 64, timeout=120.0)
+    np.testing.assert_array_equal(results[0]["sum"], results[1]["sum"])
