@@ -95,14 +95,14 @@ def pipeline_schedules() -> dict:
             # activation residency: F[s,m]'s output lives until B[s,m] runs.
             # 1F1B's raison d'être is bounding in-flight microbatches — the
             # wall-clock integral of live activations measures exactly that.
-            ev = {e["task"]: e for e in tg.trace_events}
+            ev = {e.name: e for e in tg.trace_events}
             residency = 0.0
             for s_i in range(depth):
                 for m_i in range(M):
                     f = ev.get(f"F[{s_i},{m_i}]")
                     b = ev.get(f"B[{s_i},{m_i}]")
                     if f and b:
-                        residency += max(b["t1"] - f["t0"], 0.0)
+                        residency += max(b.t1 - f.t0, 0) * 1e-9
             out[schedule] = {
                 "loss": float(loss),
                 "utilization": m["utilization"],

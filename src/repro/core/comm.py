@@ -153,6 +153,7 @@ from typing import Any, Callable, Optional, Sequence
 
 import numpy as np
 
+from . import trace
 from .access import AccessMode, SpAccess, SpData
 from .graph import SpSpeculativeModel, SpTaskGraph
 from .task import Task, TaskState, TaskView
@@ -2159,24 +2160,12 @@ class CommThread(threading.Thread):
         their input never arrived, running them would silently propagate
         garbage — and so does a completion landing inside ``stop()``'s
         grace window, when no worker is left to pop the queue."""
-        task.t_end = time.perf_counter()
+        task.t_end = time.perf_counter_ns()
         graph = getattr(task, "graph", None)
         if graph is None:  # pragma: no cover - tasks always carry a graph
             task.mark_finished()
             return
-        if getattr(graph, "trace", True):
-            graph.trace_events.append(
-                {
-                    "task": task.name,
-                    "uid": task.uid,
-                    "worker": self.name,
-                    "t0": task.t_start,
-                    "t1": task.t_end,
-                    "ready": 0,
-                    "comm": True,
-                    "spec": False,
-                }
-            )
+        trace.record_task(task, self.name, 0)
         newly = graph.on_task_finished(task)
         task.mark_finished()
         if newly:
@@ -2211,7 +2200,7 @@ class CommThread(threading.Thread):
             # re-enter submit() — neither may happen while holding _cv
             for task in starts:
                 task.state = TaskState.RUNNING
-                task.t_start = time.perf_counter()
+                task.t_start = time.perf_counter_ns()
                 try:
                     args, writebacks = task.build_args()
                     req = task.comm_start(args)
@@ -2253,7 +2242,7 @@ class CommThread(threading.Thread):
                 f"comm thread stopped with {task.name!r} still in flight"))
         for task in pending:
             self.aborted.append(task.name)
-            task.t_start = task.t_start or time.perf_counter()
+            task.t_start = task.t_start or time.perf_counter_ns()
             self._fail(task, SpCommAbortedError(
                 f"comm thread stopped before {task.name!r} started"))
 

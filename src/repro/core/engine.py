@@ -25,6 +25,7 @@ import time
 from concurrent.futures import CancelledError
 from typing import Optional
 
+from . import trace
 from .scheduler import FifoScheduler, SpAbstractScheduler, WorkStealingScheduler
 from .task import SpTaskTimeoutError, Task, TaskState
 
@@ -322,7 +323,10 @@ class SpComputeEngine:
         watched = policy is not None and policy.timeout is not None
         task.state = TaskState.RUNNING
         task.worker_name = worker.name
-        task.t_start = time.perf_counter()
+        sp = trace.task_span(task) if graph is not None else None
+        if sp is not None:
+            sp.open()
+        task.t_start = time.perf_counter_ns()
         try:
             attempt = 0
             while True:
@@ -354,7 +358,9 @@ class SpComputeEngine:
                 if policy.retry_backoff > 0.0:
                     time.sleep(policy.retry_backoff * (2 ** (attempt - 1)))
         finally:
-            task.t_end = time.perf_counter()
+            task.t_end = time.perf_counter_ns()
+            if sp is not None:
+                sp.close()
             for lk in reversed(locks):
                 lk.release()
         if watched and not task.claim_completion():
@@ -371,19 +377,8 @@ class SpComputeEngine:
                     record(task.exception)
                     task.exception = None
         if graph is not None:
-            if getattr(graph, "trace", True):
-                graph.trace_events.append(
-                    {
-                        "task": task.name,
-                        "uid": task.uid,
-                        "worker": worker.name,
-                        "t0": task.t_start,
-                        "t1": task.t_end,
-                        "ready": len(self.scheduler),
-                        "comm": task.is_comm,
-                        "spec": task.speculative,
-                    }
-                )
+            if sp is not None:
+                trace.record_task(task, worker.name, len(self.scheduler), sp)
             if (
                 task.exception is not None
                 and policy is not None

@@ -23,6 +23,7 @@ from .access import (
     SpImpl,
     SpPriority,
 )
+from . import trace
 from .handle import HandleRegistry
 from .task import Task, TaskView, normalize_impls
 
@@ -67,11 +68,12 @@ class SpTaskGraph:
         # do NOT surface through wait_all_tasks — the graph stays alive,
         # dependents are cancelled, and engine.stop() reports them by name
         self.quarantined: list[Task] = []
-        # trace events appended by the engine: dicts with task/worker/t0/t1.
-        # ``trace=False`` turns recording off so the production hot path
-        # allocates nothing per task; exports then see an empty trace.
+        # the span of every task run (core/trace.py), appended by the
+        # engine.  ``trace=False`` turns this list off so the production hot
+        # path allocates nothing per task; exports then see an empty trace.
+        # A profiler session records spans all the same (trace.SPANS).
         self.trace = trace
-        self.trace_events: list[dict] = []
+        self.trace_events: list = []
         self.spec_stats = {"speculated": 0, "commits": 0, "rollbacks": 0}
         # set by a staged SpRuntime (core/api.py): zero-arg callable that
         # executes the pending graph; TaskView.result() triggers it
@@ -164,6 +166,9 @@ class SpTaskGraph:
         comm layers call this to bypass re-speculation."""
         task.inserted_index = len(self.tasks)
         task.graph = self
+        if self.trace or trace.profiling():
+            sp = trace.current()
+            task.trace_parent = None if sp is None else sp.sid
         self.tasks.append(task)
         self._task_by_uid[task.uid] = task
         with self._cv:
@@ -333,9 +338,7 @@ class SpTaskGraph:
     generateDot = generate_dot
 
     def generate_trace(self, path: str, show_dependencies: bool = True) -> str:
-        from .trace import trace_to_svg
-
-        text = trace_to_svg(self, show_dependencies=show_dependencies)
+        text = trace.trace_to_svg(self, show_dependencies=show_dependencies)
         with open(path, "w") as f:
             f.write(text)
         return text

@@ -125,10 +125,13 @@ class Task:
         self.result: Any = None
         self.exception: BaseException | None = None
         self._done_event = threading.Event()
-        # trace metadata
+        # trace metadata: the run's stamps (``time.perf_counter_ns``) and the
+        # id of the span open where the task was inserted (set only while
+        # spans are recorded, core/trace.py)
         self.worker_name: str | None = None
-        self.t_start: float = 0.0
-        self.t_end: float = 0.0
+        self.t_start: int = 0
+        self.t_end: int = 0
+        self.trace_parent: int | None = None
         # maybe-write outcomes, filled after execution: SpData uid -> bool
         self.maybe_written: dict[int, bool] = {}
         # successors cache for dot export (filled lazily by graph)

@@ -55,6 +55,7 @@ from repro.core import (
     SpWorkerTeamBuilder,
     graph_scope,
     sp_task,
+    trace,
 )
 from repro.models import cache_layout, decode_step, init_cache, prefill
 from repro.models.config import ArchConfig
@@ -152,12 +153,12 @@ class Request:
     # continuous-batching bookkeeping
     pending_tok: Optional[int] = None  # sampled (or prompt tail) token not yet fed
     admit_order: int = -1
-    preemptions: int = 0
     # speculative-decoding telemetry
     spec_rounds: int = 0
     spec_accepted: int = 0
     # latency telemetry (perf_counter seconds), consumed by the load generator
     t_arrival: Optional[float] = None
+    t_admit: Optional[float] = None  # first admission by plan(), on the same clock
     t_first: Optional[float] = None
     t_tokens: list = field(default_factory=list)
 
@@ -209,7 +210,8 @@ def _collect_codelet(state, *, eng):
         return
     eng._caches = state["caches"]
     eng._last_tok = state["tok"]
-    toks = np.asarray(state["tok"][:, 0])
+    with trace.span("serve.device_wait"):
+        toks = np.asarray(state["tok"])[:, 0]
     now = time.perf_counter()
     for slot in sorted(eng._slot_req):
         req = eng._slot_req.get(slot)
@@ -250,6 +252,10 @@ def _prefill_codelet(out, *, eng, req, sample_first):
         [req.prompt, np.asarray(req.out_tokens[:-1], np.int32)]
     )
     prompt = np.asarray(fed, np.int32)[None, :]
+    sp = trace.current()
+    if sp is not None:
+        sp.req = req.req_id
+        sp.counts["prompt_len"] = prompt.shape[1]
     logits_last, primed = eng._prefill_prime(eng.params, {"tokens": jnp.asarray(prompt)})
     first = eng._sample_one(req, logits_last[0]) if sample_first else None
     out.value = (primed, first, prompt.shape[1])
@@ -261,6 +267,9 @@ def _install_codelet(state, out, *, eng, req, slot):
     # task and its cells, so a filled cell would pin one slot's worth of
     # KV cache in device memory for each admission the engine ever made
     (primed, first, n_fed), out.value = out.value, None
+    sp = trace.current()
+    if sp is not None:
+        sp.req = req.req_id
     st = state.value
     if first is not None:
         req.out_tokens.append(first)
@@ -284,6 +293,9 @@ def _install_codelet(state, out, *, eng, req, slot):
 def _restore_codelet(state, *, eng, req, slot, rows, n_rows):
     """Prefix-cache hit / resume: scatter saved KV rows into the slot and
     join the decode batch with no prefill at all."""
+    sp = trace.current()
+    if sp is not None:
+        sp.req = req.req_id
     st = state.value
     caches = insert_cache_rows(st["caches"], slot, rows, 0)
     tok = st["tok"].at[slot, 0].set(req.pending_tok)
@@ -446,26 +458,33 @@ class ServeEngine:
         round planning reads slot state the previous round must have
         committed."""
         spec_round = False
-        with graph_scope(self._tg):
-            if self._slot_req:
-                spec_slots = [
-                    s for s, r in self._slot_req.items() if r.speculative
-                ] if self._spec is not None else []
-                k = 0
-                if spec_slots:
-                    k = self.scheduler.draft_depth(len(spec_slots))
-                    if k <= 0:
-                        self._spec.sheds += 1  # pool pressure: plain decode
-                if spec_slots and k > 0:
-                    self._spec.insert_round(spec_slots, k)
-                    spec_round = True
-                else:
-                    _decode_codelet(self._state, eng=self)
-                    _collect_codelet(self._state, eng=self)
-            for adm in self.scheduler.plan(pageable=self._pageable):
-                self._insert_admission(adm)
-        if wait or spec_round:
-            self._tg.wait_all_tasks()
+        with trace.span("serve.step") as sp:
+            n_decoding = len(self._slot_req)
+            with graph_scope(self._tg):
+                if self._slot_req:
+                    spec_slots = [
+                        s for s, r in self._slot_req.items() if r.speculative
+                    ] if self._spec is not None else []
+                    k = 0
+                    if spec_slots:
+                        k = self.scheduler.draft_depth(len(spec_slots))
+                        if k <= 0:
+                            self._spec.sheds += 1  # pool pressure: plain decode
+                    if spec_slots and k > 0:
+                        self._spec.insert_round(spec_slots, k)
+                        spec_round = True
+                    else:
+                        _decode_codelet(self._state, eng=self)
+                        _collect_codelet(self._state, eng=self)
+                with trace.span("serve.plan"):
+                    plan = self.scheduler.plan(pageable=self._pageable)
+                for adm in plan:
+                    self._insert_admission(adm)
+            if sp is not None:
+                sp.counts.update(decoding=n_decoding, admitted=len(plan))
+            if wait or spec_round:
+                with trace.span("serve.wait"):
+                    self._tg.wait_all_tasks()
         self.steps += 1
 
     def run_until_drained(self, max_iters: int = 1000) -> None:
@@ -542,12 +561,18 @@ class ServeEngine:
         if table is None:
             return
         bs = self.pool.block_size
-        for i, bid in enumerate(table.block_ids):
-            blk = self.pool.block(bid)
-            a = i * bs
-            b = min(a + len(blk.tokens), table.n_tokens)
-            if blk.payload is None or blk.refcount <= 1:
-                blk.payload = extract_cache_rows(self._caches, slot, a, b)
+        with trace.span("serve.writeback", req.req_id) as sp:
+            blocks = rows = 0
+            for i, bid in enumerate(table.block_ids):
+                blk = self.pool.block(bid)
+                a = i * bs
+                b = min(a + len(blk.tokens), table.n_tokens)
+                if blk.payload is None or blk.refcount <= 1:
+                    blk.payload = extract_cache_rows(self._caches, slot, a, b)
+                    blocks += 1
+                    rows += b - a
+            if sp is not None:
+                sp.counts.update(blocks=blocks, rows=rows)
 
     def _emit_token(self, req: Request, tok: int) -> None:
         """Fire the streaming callback for one committed token."""
@@ -597,10 +622,10 @@ class ServeEngine:
         """Evict a running sequence: save its KV rows, release its blocks
         (resumable), and requeue it at the head of the admission queue."""
         req = self._slot_req.pop(slot)
-        self._writeback(slot, req)
+        with trace.span("serve.preempt", req.req_id):
+            self._writeback(slot, req)
         self.pool.release(req.req_id, keep_resident=True)
         self.scheduler.free_slot(slot)
-        req.preemptions += 1
         self.scheduler.requeue(req)
         if self._spec is not None:
             self._spec.drop_slot(slot)
@@ -628,20 +653,27 @@ class ServeEngine:
         temps = np.zeros(B, np.float32)
         topks = np.zeros(B, np.int32)
         keys = np.zeros((B, 2), np.uint32)
-        for slot, r in reqs.items():
-            temps[slot] = r.temperature
-            topks[slot] = r.top_k
-            if r.temperature > 0.0:
-                keys[slot] = np.asarray(jax.random.fold_in(
-                    jax.random.PRNGKey(r.seed), len(r.prompt) + len(r.out_tokens)
-                ))
+        with trace.span("serve.sample_keys") as sp:
+            for slot, r in reqs.items():
+                temps[slot] = r.temperature
+                topks[slot] = r.top_k
+                if r.temperature > 0.0:
+                    key = jax.random.fold_in(
+                        jax.random.PRNGKey(r.seed), len(r.prompt) + len(r.out_tokens)
+                    )
+                    with trace.span("serve.device_wait"):
+                        keys[slot] = np.asarray(key)
+            if sp is not None:
+                sp.counts["sampled"] = int((temps > 0.0).sum())
         return self._sample_jit(
             logits, jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(keys)
         )
 
     def _sample_one(self, req: Request, logits: jax.Array) -> int:
         if req.temperature <= 0.0:
-            return int(jnp.argmax(logits))
+            best = jnp.argmax(logits)
+            with trace.span("serve.device_wait"):
+                return int(best)
         key = jax.random.fold_in(
             jax.random.PRNGKey(req.seed), len(req.prompt) + len(req.out_tokens)
         )
@@ -651,7 +683,8 @@ class ServeEngine:
             jnp.asarray([req.top_k], jnp.int32),
             jnp.asarray(key, jnp.uint32)[None, :],
         )
-        return int(tok[0])
+        with trace.span("serve.device_wait"):
+            return int(tok[0])
 
 
 def _sample_logits(logits, temps, topks, keys):

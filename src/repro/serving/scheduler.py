@@ -220,6 +220,8 @@ class ServeScheduler:
                 self._waiting.popleft()
                 slot = self._free_slots.pop(0)
                 req.admit_order = next(self._admit_seq)
+                if getattr(req, "t_admit", None) is None:
+                    req.t_admit = now
                 self.admitted += 1
                 out.append(Admission(req, slot, mode))
         return out
