@@ -34,10 +34,17 @@ old one-slot-per-sequence pool to real paging:
   the rows instead of re-running prefill; an evicted block drops its
   payload, so an evict-then-resume goes back through prefill (the
   recompute-instead-of-transfer trade that suits XLA-owned HBM).
+
+* **Threads** — the engine's planner allocates on its own thread while the
+  step's collect task appends, finishes and preempts on a worker, and both
+  may evict.  Every method that changes the pool or walks its blocks holds
+  the pool's lock.
 """
 from __future__ import annotations
 
+import functools
 import itertools
+import threading
 from dataclasses import dataclass, field
 from typing import Any, Iterable, Optional, Sequence
 
@@ -48,6 +55,15 @@ class PageError(RuntimeError):
 
 #: Chain key of the empty prefix (the root of the content-addressed trie).
 _ROOT = ("kv-root",)
+
+
+def _locked(method):
+    @functools.wraps(method)
+    def locked(self, *args, **kwargs):
+        with self._lock:
+            return method(self, *args, **kwargs)
+
+    return locked
 
 
 @dataclass
@@ -97,6 +113,7 @@ class KVPagePool:
             raise ValueError("n_blocks and block_size must be >= 1")
         self.n_blocks = n_blocks
         self.block_size = block_size
+        self._lock = threading.RLock()
         self._blocks: dict[int, KVBlock] = {}
         self._tables: dict[int, BlockTable] = {}     # actively decoding
         self._resident: dict[int, BlockTable] = {}   # released, resumable
@@ -182,6 +199,7 @@ class KVPagePool:
 
     # ----------------------------------------------------------------- alloc
 
+    @_locked
     def allocate(self, seq_id: int, tokens: Sequence[int]) -> BlockTable:
         """Build a block table for ``tokens``, sharing every content-matched
         block (refcount++) and allocating the rest; atomic — on PageError the
@@ -220,6 +238,7 @@ class KVPagePool:
         self._tables[seq_id] = table
         return table
 
+    @_locked
     def append_token(self, seq_id: int, token: int) -> dict:
         """Record one more fed token for ``seq_id``.  May allocate a fresh
         block (last one full) or copy-on-write a shared partial block.
@@ -259,6 +278,7 @@ class KVPagePool:
     # staged range per sequence — re-staging overwrites (a rolled-back verify
     # re-runs and restages idempotently).
 
+    @_locked
     def stage_rows(self, seq_id: int, start: int, rows: Any) -> None:
         """Park uncommitted KV rows for positions ``[start, start+len)``."""
         if seq_id not in self._tables:
@@ -281,6 +301,7 @@ class KVPagePool:
 
     # --------------------------------------------------------------- release
 
+    @_locked
     def release(self, seq_id: int, *, keep_resident: bool = True) -> None:
         """Drop the sequence's references.  ``keep_resident=True`` keeps the
         table resumable and the blocks cached (evictable once unreferenced);
@@ -303,6 +324,7 @@ class KVPagePool:
                 if blk is not None and blk.refcount == 0:
                     self._drop_block(blk)
 
+    @_locked
     def resume(self, seq_id: int) -> Optional[BlockTable]:
         """Re-pin a released sequence's blocks.  Returns its table if every
         block survived eviction, else None (caller must re-prefill)."""
@@ -320,6 +342,7 @@ class KVPagePool:
 
     # ---------------------------------------------------------------- lookup
 
+    @_locked
     def probe_restore(self, tokens: Sequence[int]) -> bool:
         """True when every block that :meth:`allocate` would share for
         ``tokens`` is live *and payload-backed* — i.e. the engine can restore
@@ -347,12 +370,14 @@ class KVPagePool:
     def table_of(self, seq_id: int) -> Optional[BlockTable]:
         return self._tables.get(seq_id)
 
+    @_locked
     def blocks_of(self, seq_id: int) -> list[KVBlock]:
         table = self._tables.get(seq_id) or self._resident.get(seq_id)
         if table is None:
             return []
         return [self._blocks[b] for b in table.block_ids if b in self._blocks]
 
+    @_locked
     def resident(self, seq_id: int) -> bool:
         table = self._resident.get(seq_id)
         return table is not None and all(b in self._blocks for b in table.block_ids)
@@ -368,6 +393,7 @@ class KVPagePool:
         return self.n_blocks - len(self._blocks)
 
     @property
+    @_locked
     def n_evictable(self) -> int:
         return sum(1 for b in self._blocks.values() if b.refcount == 0)
 
@@ -375,6 +401,7 @@ class KVPagePool:
     def occupancy(self) -> float:
         return len(self._blocks) / self.n_blocks
 
+    @_locked
     def stats(self) -> dict:
         return {
             "n_blocks": self.n_blocks,

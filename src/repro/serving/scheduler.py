@@ -181,10 +181,11 @@ class ServeScheduler:
 
     def plan(self, *, pageable: bool) -> list[Admission]:
         """Admit waiting requests while slots and blocks allow.  Block
-        allocation happens here (driver thread, graph drained) so the
-        admission either fully reserves its memory or stays queued.
-        Cancelled and deadline-expired waiters are dropped first — admitting
-        them would spend prefill compute and KV blocks on dead work."""
+        allocation happens here (on the thread that calls ``step()``, while
+        the step's collect task may run) so the admission either fully
+        reserves its memory or stays queued.  Cancelled and deadline-expired
+        waiters are dropped first — admitting them would spend prefill
+        compute and KV blocks on dead work."""
         out: list[Admission] = []
         now = time.perf_counter()
         with self._lock:

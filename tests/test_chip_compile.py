@@ -2,8 +2,8 @@
 
 The TPU compiler is installed next to JAX, and it compiles for a chip that
 is described rather than attached.  These tests compile the Pallas kernels
-at deepseek-7b widths and the served decode and prefill programs at
-``chip_smoke.py``'s sizes for one v5e chip: the compiler refuses what the
+at deepseek-7b widths and the served decode, prefill and KV-writeback
+gather programs at ``chip_smoke.py``'s sizes for one v5e chip: the compiler refuses what the
 chip would refuse (misaligned tiles, too much VMEM, a program larger than
 the chip's memory) at no chip time.  Nothing runs, so nothing here is a
 time or a result.
@@ -14,6 +14,7 @@ test workers import every test file.  Keep these tests in this one file.
 """
 from __future__ import annotations
 
+import math
 import os
 import sys
 from pathlib import Path
@@ -135,4 +136,23 @@ def test_served_prefill_fits_one_chip(one_chip, smoke_cfg):
         _on(one_chip, abstract_params(smoke_cfg)),
         {"tokens": _sds((1, L), "int32", one_chip)},
     ).compile()
+    _fits(compiled)
+
+
+def test_writeback_gather_fits_one_chip(one_chip, smoke_cfg):
+    """The KV-writeback gather at the smoke's slots and max_seq: one
+    chunk of one slot out, with no copy of the cache it is cut from."""
+    from repro.models import abstract_cache
+    from repro.serving.engine import WRITEBACK_CHUNK, _jitted_gather
+
+    n, S = chip_smoke.SERVE["n_slots"], chip_smoke.SERVE["max_seq"]
+    caches = abstract_cache(smoke_cfg, n, S)
+    compiled = _jitted_gather(smoke_cfg, S).lower(
+        _on(one_chip, caches), _sds((), "int32", one_chip), _sds((), "int32", one_chip),
+    ).compile()
+    chunk = sum(math.prod(x.shape) // (n * S) * WRITEBACK_CHUNK * x.dtype.itemsize
+                for x in jax.tree.leaves(caches))
+    ma = compiled.memory_analysis()
+    assert chunk <= ma.output_size_in_bytes <= chunk + 4096  # and the output tuple's table
+    assert ma.temp_size_in_bytes < chunk
     _fits(compiled)

@@ -119,6 +119,45 @@ def test_pool_page_error_when_all_pinned():
         pool.append_token(1, 5)  # needs a second block; only one, pinned
 
 
+def test_pool_planner_and_collect_threads_share_the_pool():
+    """``ServeEngine.step`` plans admissions on its own thread while the
+    step's collect task appends, finishes and evicts on a worker: both
+    allocate blocks from one full pool, so both evict.  Unserialised, two
+    evictions pick the same victim (a KeyError) or one iterates the blocks
+    while the other drops one."""
+    import sys
+    import threading
+
+    pool = KVPagePool(48, block_size=4)
+    errors = []
+
+    def run(first_id, appends):
+        rng = np.random.default_rng(first_id)
+        try:
+            for seq in range(first_id, first_id + 400):
+                pool.allocate(seq, rng.integers(0, 1000, size=9).tolist())
+                for tok in rng.integers(0, 1000, size=appends):
+                    pool.append_token(seq, int(tok))
+                pool.probe_restore(rng.integers(0, 1000, size=5).tolist())
+                pool.release(seq, keep_resident=True)
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=run, args=(10**6 * i, 3 * i)) for i in (1, 2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        sys.setswitchinterval(interval)
+    assert errors == []
+    assert pool.n_live <= pool.n_blocks
+    assert pool.n_evictable == pool.n_live  # every sequence released its pins
+
+
 # ---------------------------------------------------------------------------
 # scheduler: bounded admission, overload policies, backpressure
 # ---------------------------------------------------------------------------

@@ -14,12 +14,14 @@ import pytest
 
 from repro.configs import reduced_config
 from repro.core import SpComputeEngine, SpData, SpTaskGraph, SpWorkerTeamBuilder, SpWrite, trace
-from repro.models import init_params
+from repro.models import init_cache, init_params
 from repro.serving import ServeEngine
+from repro.serving.engine import WRITEBACK_CHUNK
 
 pytestmark = pytest.mark.timeout(180)
 
 BLOCK = 4
+MAX_SEQ = 32
 
 
 @pytest.fixture(scope="module")
@@ -55,7 +57,7 @@ def recorded(served, tmp_path_factory):
     of the profile's host events."""
     cfg, params = served
     d = tmp_path_factory.mktemp("profile")
-    with ServeEngine(cfg, params, n_slots=4, max_seq=32, block_size=BLOCK) as eng:
+    with ServeEngine(cfg, params, n_slots=4, max_seq=MAX_SEQ, block_size=BLOCK) as eng:
         _drive(eng, cfg, 1)  # compile outside the session
         tables = {}
         release = eng.pool.release
@@ -96,15 +98,20 @@ def test_served_run_records_step_spans_as_parents_of_its_tasks(recorded):
     assert all(steps[s.parent].thread != s.thread for s in by["serve.decode"])
 
 
-def test_one_writeback_per_finished_request_of_its_whole_block_table(recorded):
+def test_one_writeback_per_finished_request_of_its_whole_block_table(served, recorded):
     spans, reqs, tables, _ = recorded
     wb = [s for s in spans if s.name == "serve.writeback"]
     assert sorted(s.req for s in wb) == sorted(r.req_id for r in reqs)
     collects = {s.sid for s in spans if s.name == "serve.collect"}
+    chunk = min(WRITEBACK_CHUNK, MAX_SEQ)
+    cfg = served[0]
+    row_bytes = sum(x.nbytes for x in jax.tree.leaves(init_cache(cfg, 1, 1)))  # both leaves
     for s in wb:
         req = next(r for r in reqs if r.req_id == s.req)
         rows = len(req.prompt) + len(req.out_tokens) - 1  # the last token is never fed
-        assert s.counts == {"blocks": tables[s.req], "rows": rows}
+        chunks = math.ceil(rows / chunk)  # every block is written: the spans cover them all
+        assert s.counts == {"blocks": tables[s.req], "rows": rows, "chunks": chunks,
+                            "bytes": chunks * chunk * row_bytes}
         assert tables[s.req] == math.ceil(rows / BLOCK)
         assert s.parent in collects
 
