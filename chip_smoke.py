@@ -146,7 +146,7 @@ def check_decode_matches_prefill(cfg, params, *, n_slots: int, max_seq: int, pro
     decode, prefill = _jitted_steps(cfg)
     prefill_prime, install = _jitted_serve_ops(cfg, max_seq)
     L = len(prompt)
-    last, primed = prefill_prime(params, {"tokens": jnp.asarray(prompt[None])})
+    last, primed, _ = prefill_prime(params, {"tokens": jnp.asarray(prompt[None])})
     nxt = int(jnp.argmax(last[0]))
     caches, tok = install(
         init_cache(cfg, n_slots, max_seq), primed,
@@ -154,7 +154,7 @@ def check_decode_matches_prefill(cfg, params, *, n_slots: int, max_seq: int, pro
     )
     pos = np.zeros(n_slots, np.int32)
     pos[0] = L
-    got, _ = decode(params, tok, caches, jnp.asarray(pos))
+    got, _, _ = decode(params, tok, caches, jnp.asarray(pos))
     extended = np.append(prompt, nxt).astype(np.int32)
     want, _ = prefill(params, {"tokens": jnp.asarray(extended[None])})
     got = np.asarray(got[0, 0], np.float32)

@@ -9,6 +9,7 @@ from repro.models.config import ArchConfig, HybridConfig, MLAConfig, MoEConfig, 
 
 from . import (
     deepseek_7b,
+    deepseek_v2_lite,
     gemma_7b,
     hubert_xlarge,
     internvl2_2b,
@@ -33,6 +34,8 @@ ARCHS: dict[str, ArchConfig] = {
         mamba2_130m.CONFIG,
         recurrentgemma_9b.CONFIG,
         internvl2_2b.CONFIG,
+        deepseek_v2_lite.CONFIG,
+        deepseek_v2_lite.SHARE,
     ]
 }
 
@@ -68,10 +71,20 @@ def reduced_config(name: str) -> ArchConfig:
             cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2), d_ff_expert=32
         )
         kw["d_ff"] = 32
+        if cfg.moe.dispatch == "ragged":  # 8 experts, 2 held by a share
+            kw["moe"] = dataclasses.replace(
+                cfg.moe, n_experts=8, top_k=3, d_ff_expert=32,
+                held=None if cfg.moe.held is None else (0, 2),
+            )
+            kw["d_ff"] = 96  # the leading dense layer's own width
     if cfg.mla is not None:
         kw["mla"] = MLAConfig(
-            q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
-            qk_rope_head_dim=8, v_head_dim=16,
+            q_lora_rank=None if cfg.mla.q_lora_rank is None else 32,
+            kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        )
+    if cfg.rope_scaling is not None:  # a 64-position original context
+        kw["rope_scaling"] = dataclasses.replace(
+            cfg.rope_scaling, original_max_position_embeddings=64
         )
     if cfg.ssm is not None:
         kw["ssm"] = SSMConfig(d_state=16, head_dim=8, expand=2, n_groups=1, chunk_size=8)

@@ -92,7 +92,9 @@ def reference_attention(
     causal: bool = True,
     window: Optional[int] = None,
     q_offset: int = 0,
+    scale: Optional[float] = None,
 ) -> jax.Array:
+    """``scale`` multiplies the scores in place of ``1/sqrt(head_dim)``."""
     B, Lq, H, Dh = q.shape
     _, Lk, KH, _ = k.shape
     G = H // KH
@@ -103,7 +105,7 @@ def reference_attention(
         k = jnp.repeat(k, G, axis=2)
         v = jnp.repeat(v, G, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
-    s = s / math.sqrt(Dh)
+    s = s / math.sqrt(Dh) if scale is None else s * scale
     qpos = q_offset + jnp.arange(Lq)
     kpos = jnp.arange(Lk)
     mask = jnp.ones((Lq, Lk), bool)
@@ -137,7 +139,8 @@ import functools
 
 
 @functools.lru_cache(maxsize=64)
-def _make_flash(causal: bool, window: Optional[int], block_kv: int, q_offset: int, mode: str, unroll: bool = False):
+def _make_flash(causal: bool, window: Optional[int], block_kv: int, q_offset: int, mode: str, unroll: bool = False,
+                sm_scale: Optional[float] = None):
     """Factory for a custom-VJP blockwise attention with the FlashAttention-2
     backward: residuals are only (q, k, v, out, lse) — scores are recomputed
     per KV block in the backward scan, so memory back through the layer-remat
@@ -162,7 +165,7 @@ def _make_flash(causal: bool, window: Optional[int], block_kv: int, q_offset: in
             v = jnp.repeat(v, G, axis=2)
         bk = min(block_kv, Lk)
         nk = Lk // bk
-        scale = 1.0 / math.sqrt(Dh)
+        scale = 1.0 / math.sqrt(Dh) if sm_scale is None else sm_scale
         kb = k.reshape(B, nk, bk, H, Dh).swapaxes(0, 1)
         vb = v.reshape(B, nk, bk, H, Dv).swapaxes(0, 1)
         qpos = q_offset + jnp.arange(Lq)
@@ -221,7 +224,7 @@ def _make_flash(causal: bool, window: Optional[int], block_kv: int, q_offset: in
             v = jnp.repeat(v, G, axis=2)
         bk = min(block_kv, Lk)
         nk = Lk // bk
-        scale = 1.0 / math.sqrt(Dh)
+        scale = 1.0 / math.sqrt(Dh) if sm_scale is None else sm_scale
         kb = k.reshape(B, nk, bk, H, Dh).swapaxes(0, 1)
         vb = v.reshape(B, nk, bk, H, Dv).swapaxes(0, 1)
         do = dout.transpose(0, 2, 1, 3).astype(jnp.float32)  # (B,H,Lq,Dv)
@@ -280,10 +283,11 @@ def blockwise_attention(
     q_offset: int = 0,
     mode: str = "masked",
     unroll: bool = False,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Online-softmax attention; never materializes (Lq, Lk) scores."""
     if mode == "masked":
-        fn = _make_flash(causal, window, block_kv, q_offset, mode, unroll)
+        fn = _make_flash(causal, window, block_kv, q_offset, mode, unroll, scale)
         return fn(q, k, v)
     B, Lq, H, Dh = q.shape
     _, Lk, KH, _ = k.shape
@@ -297,7 +301,7 @@ def blockwise_attention(
     bk = min(block_kv, Lk)
     assert Lq % bq == 0 and Lk % bk == 0, (Lq, bq, Lk, bk)
     nq, nk = Lq // bq, Lk // bk
-    scale = 1.0 / math.sqrt(Dh)
+    scale = 1.0 / math.sqrt(Dh) if scale is None else scale
 
     qb = q.reshape(B, nq, bq, KH, G, Dh)
     kb = k.reshape(B, nk, bk, KH, Dh)
@@ -362,11 +366,13 @@ def full_attention(
     window: Optional[int] = None,
     q_offset: int = 0,
     mode: Optional[str] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Dispatch: reference below the blockwise threshold, blockwise above;
-    Pallas kernel when enabled on TPU (kernels/flash_attention/ops.py)."""
+    Pallas kernel when enabled on TPU (kernels/flash_attention/ops.py) and
+    the scores take the default ``1/sqrt(head_dim)`` scale."""
     Lq = q.shape[1]
-    if cfg.use_pallas:
+    if cfg.use_pallas and scale is None:
         from repro.kernels.flash_attention import ops as fa_ops
 
         if fa_ops.available() and Lq >= cfg.attn_blockwise_min_seq:
@@ -374,7 +380,9 @@ def full_attention(
                 q, k, v, causal=causal, window=window, q_offset=q_offset
             )
     if Lq < cfg.attn_blockwise_min_seq:
-        return reference_attention(q, k, v, causal=causal, window=window, q_offset=q_offset)
+        return reference_attention(
+            q, k, v, causal=causal, window=window, q_offset=q_offset, scale=scale
+        )
     mode = mode or cfg.attn_mode
     if mode == "auto":
         # tri needs head-sharded attention (EXPERIMENTS.md §Perf bonus round:
@@ -396,6 +404,7 @@ def full_attention(
         q_offset=q_offset,
         mode=mode,
         unroll=cfg.probe_unroll,
+        scale=scale,
     )
 
 

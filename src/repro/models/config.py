@@ -23,19 +23,52 @@ class MoEConfig:
     capacity_factor: float = 1.25
     # 'einsum'  — GShard one-hot dispatch (baseline; inflates HLO FLOPs)
     # 'scatter' — sort/scatter dispatch (optimized; matmul FLOPs ≈ useful)
+    # 'ragged'  — sort + grouped matmul over the held experts: no capacity,
+    #             no dropped token
     dispatch: str = "einsum"
     router_dtype: str = "float32"
+    # top-k weights renormalised to sum to one (qwen3-moe, llama4-scout), or
+    # kept as the softmax gives them and scaled by routed_scaling_factor
+    # (DeepSeek-V2: norm_topk_prob false)
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # expert parallelism ('ragged' only): this layer holds routed experts
+    # [first, first + count) of n_experts and computes only their part of the
+    # result; None holds all of them
+    held: Optional[tuple[int, int]] = None
+    # leading layers with a dense MLP of width ArchConfig.d_ff before the
+    # expert layers (DeepSeek-V2's first_k_dense_replace)
+    first_k_dense: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.held is None else self.held[1]
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """Multi-head latent attention (DeepSeek-V2 / MiniCPM3)."""
+    """Multi-head latent attention (DeepSeek-V2 / MiniCPM3).  ``q_lora_rank``
+    None projects queries directly (DeepSeek-V2-Lite)."""
 
-    q_lora_rank: int
+    q_lora_rank: Optional[int]
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (rope_scaling type 'yarn', as DeepSeek-V2 publishes
+    it): interpolated low frequencies, extrapolated high ones, a linear ramp
+    between, and the softmax scale multiplied by ``mscale(mscale_all_dim)²``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -76,6 +109,7 @@ class ArchConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     attn_window: Optional[int] = None  # sliding-window size, None = full
@@ -176,9 +210,11 @@ def _count_params(cfg: ArchConfig, active_only: bool) -> int:
     def attn_params() -> int:
         if cfg.mla is not None:
             m = cfg.mla
-            p = d * m.q_lora_rank + m.q_lora_rank * cfg.n_heads * (
-                m.qk_nope_head_dim + m.qk_rope_head_dim
-            )
+            qk = cfg.n_heads * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+            if m.q_lora_rank is None:
+                p = d * qk
+            else:
+                p = d * m.q_lora_rank + m.q_lora_rank * qk
             p += d * (m.kv_lora_rank + m.qk_rope_head_dim)
             p += m.kv_lora_rank * cfg.n_heads * (m.qk_nope_head_dim + m.v_head_dim)
             p += cfg.n_heads * m.v_head_dim * d
@@ -224,16 +260,18 @@ def _count_params(cfg: ArchConfig, active_only: bool) -> int:
             n += mlp_params(cfg.d_ff)
             continue
         n += attn_params()
-        if cfg.moe is not None:
-            e_params = mlp_params(cfg.moe.d_ff_expert)
-            n_routed = cfg.moe.top_k if active_only else cfg.moe.n_experts
+        moe = cfg.moe
+        if moe is not None and li >= moe.first_k_dense:
+            e_params = mlp_params(moe.d_ff_expert)
+            # of the experts held here, a token runs top_k / n_experts
+            n_routed = moe.top_k * moe.n_held / moe.n_experts if active_only else moe.n_held
             n += n_routed * e_params
-            n += cfg.moe.n_shared_experts * e_params
-            n += d * cfg.moe.n_experts  # router
+            n += moe.n_shared_experts * e_params
+            n += d * moe.n_experts  # router
         else:
             n += mlp_params(cfg.d_ff)
     n += d  # final norm
-    return n
+    return round(n)
 
 
 @dataclass(frozen=True)

@@ -5,13 +5,15 @@ via :func:`repro.dist.sharding.shard` (identity off-mesh).
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.dist.sharding import shard
-from repro.models.config import ArchConfig
+from repro.models.config import ArchConfig, YarnConfig
 from repro.models.param import ParamDef
 
 
@@ -41,10 +43,42 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
-    """x: (..., L, n, head_dim); positions: (..., L) int32."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor, ``0.1·mscale·ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, y: YarnConfig) -> np.ndarray:
+    """YaRN inverse frequencies (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``):
+    the rotations that turn fewer than ``beta_slow`` times over the original
+    context are interpolated (divided by ``factor``), those that turn more
+    than ``beta_fast`` times are kept, with a linear ramp between."""
+    pos = np.arange(0, head_dim, 2, dtype=np.float32) / np.float32(head_dim)
+    extra = 1.0 / np.float32(theta) ** pos
+    inter = 1.0 / (np.float32(y.factor) * np.float32(theta) ** pos)
+
+    def dim_of(rotations):
+        return head_dim * math.log(
+            y.original_max_position_embeddings / (rotations * 2 * math.pi)
+        ) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(y.beta_fast)), 0)
+    high = min(math.ceil(dim_of(y.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low) / (high - low), 0, 1)
+    keep = 1.0 - ramp  # 1: extrapolated (high frequency), 0: interpolated
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(
+    x: jax.Array, positions: jax.Array, theta: float = 10000.0, *, freqs=None
+) -> jax.Array:
+    """x: (..., L, n, head_dim); positions: (..., L) int32.  ``freqs``
+    (head_dim/2,) overrides the plain ``theta`` frequencies (YaRN)."""
     head_dim = x.shape[-1]
-    freqs = rope_frequencies(head_dim, theta)  # (half,)
+    if freqs is None:
+        freqs = rope_frequencies(head_dim, theta)  # (half,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., L, half)
     sin = jnp.sin(angles)[..., None, :]
     cos = jnp.cos(angles)[..., None, :]

@@ -5,6 +5,13 @@ latent (c_kv) and the shared RoPE key are cached at decode time — the KV
 cache is ~(r_kv + d_rope)/(2·H·Dh) the size of a GQA cache.  Decode uses the
 *absorbed* formulation: W_UK folds into the query and W_UV into the output,
 so attention runs directly in latent space against the compact cache.
+
+DeepSeek-V2-Lite has no query LoRA (``q_lora_rank`` None: one direct
+projection ``wq``) and YaRN rope scaling (``cfg.rope_scaling``): YaRN
+frequencies for the rope part of queries and keys, and the softmax scale
+multiplied by ``mscale(mscale_all_dim)²``.  The rope columns are rotated in
+rotate-half form as stored; the published checkpoint interleaves them, which
+a loader undoes with one fixed permutation of those weight columns.
 """
 from __future__ import annotations
 
@@ -15,7 +22,7 @@ import jax.numpy as jnp
 
 from repro.dist.sharding import shard
 from repro.models.config import ArchConfig
-from repro.models.layers import apply_rope, rmsnorm
+from repro.models.layers import apply_rope, rmsnorm, yarn_frequencies, yarn_mscale
 from repro.models.param import ParamDef
 from repro.models.attention import full_attention, kv_cache_update
 
@@ -26,10 +33,16 @@ def mla_defs(cfg: ArchConfig) -> dict:
     m = cfg.mla
     D, H = cfg.d_model, cfg.n_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
+    if m.q_lora_rank is None:
+        q = {"wq": ParamDef((D, H, qk), ("embed", "heads", None))}
+    else:
+        q = {
+            "wq_a": ParamDef((D, m.q_lora_rank), ("embed", None)),
+            "q_norm": ParamDef((m.q_lora_rank,), (None,), init="zeros"),
+            "wq_b": ParamDef((m.q_lora_rank, H, qk), (None, "heads", None)),
+        }
     return {
-        "wq_a": ParamDef((D, m.q_lora_rank), ("embed", None)),
-        "q_norm": ParamDef((m.q_lora_rank,), (None,), init="zeros"),
-        "wq_b": ParamDef((m.q_lora_rank, H, qk), (None, "heads", None)),
+        **q,
         "wkv_a": ParamDef((D, m.kv_lora_rank + m.qk_rope_head_dim), ("embed", None)),
         "kv_norm": ParamDef((m.kv_lora_rank,), (None,), init="zeros"),
         "wk_b": ParamDef((m.kv_lora_rank, H, m.qk_nope_head_dim), (None, "heads", None)),
@@ -38,13 +51,35 @@ def mla_defs(cfg: ArchConfig) -> dict:
     }
 
 
+def _rope(x: jax.Array, positions: jax.Array, cfg: ArchConfig) -> jax.Array:
+    y = cfg.rope_scaling
+    if y is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    freqs = yarn_frequencies(x.shape[-1], cfg.rope_theta, y)
+    out = apply_rope(x, positions, cfg.rope_theta, freqs=freqs)
+    gain = yarn_mscale(y.factor, y.mscale) / yarn_mscale(y.factor, y.mscale_all_dim)
+    return out if gain == 1.0 else (out * gain).astype(x.dtype)
+
+
+def softmax_scale(cfg: ArchConfig) -> float:
+    """Scale of the q·k scores: ``1/sqrt(qk head dim)``, times
+    ``mscale(mscale_all_dim)²`` under YaRN."""
+    m, y = cfg.mla, cfg.rope_scaling
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def _project_q(p: dict, x: jax.Array, positions: jax.Array, cfg: ArchConfig):
     m = cfg.mla
-    cq = rmsnorm(jnp.einsum("bld,dr->blr", x, p["wq_a"]), p["q_norm"], cfg.norm_eps)
-    q = jnp.einsum("blr,rhk->blhk", cq, p["wq_b"])
+    if m.q_lora_rank is None:
+        q = jnp.einsum("bld,dhk->blhk", x, p["wq"])
+    else:
+        cq = rmsnorm(jnp.einsum("bld,dr->blr", x, p["wq_a"]), p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("blr,rhk->blhk", cq, p["wq_b"])
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    return q_nope, q_rope
+    return q_nope, _rope(q_rope, positions, cfg)
 
 
 def _project_kv_latent(p: dict, x: jax.Array, positions: jax.Array, cfg: ArchConfig):
@@ -52,7 +87,7 @@ def _project_kv_latent(p: dict, x: jax.Array, positions: jax.Array, cfg: ArchCon
     kv = jnp.einsum("bld,dr->blr", x, p["wkv_a"])
     c_kv, k_rope = jnp.split(kv, [m.kv_lora_rank], axis=-1)
     c_kv = rmsnorm(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = _rope(k_rope[:, :, None, :], positions, cfg)[:, :, 0]
     return c_kv, k_rope  # (B,L,r_kv), (B,L,d_rope)
 
 
@@ -78,7 +113,8 @@ def mla_apply(
     q = shard(q, "batch", None, "heads", None)
     k = shard(k, "batch", None, "heads", None)
     v = shard(v, "batch", None, "heads", None)
-    out = full_attention(q, k, v, cfg, causal=causal, window=cfg.attn_window)
+    scale = None if cfg.rope_scaling is None else softmax_scale(cfg)
+    out = full_attention(q, k, v, cfg, causal=causal, window=cfg.attn_window, scale=scale)
     y = jnp.einsum("blhv,hvd->bld", out, p["wo"])
     y = shard(y, "batch", "act_seq", None)
     if want_cache:
@@ -116,7 +152,7 @@ def mla_decode_step(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg: Arc
     s = s + jnp.einsum(
         "bhk,bsk->bhs", q_rope[:, 0].astype(jnp.float32), r_cache.astype(jnp.float32)
     )
-    s = s / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    s = s * softmax_scale(cfg)
     pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
     valid = jnp.arange(S)[None, :] <= pos_b[:, None]
     s = jnp.where(valid[:, None, :], s, NEG_INF)

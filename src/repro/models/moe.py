@@ -1,6 +1,7 @@
-"""Mixture-of-Experts layer with expert parallelism (qwen3-moe, llama4-scout).
+"""Mixture-of-Experts layer with expert parallelism (qwen3-moe, llama4-scout,
+DeepSeek-V2-Lite).
 
-Two dispatch strategies — the central §Perf lever for the MoE cells:
+Three dispatch strategies — the central §Perf lever for the MoE cells:
 
 * ``einsum``  — GShard-style grouped one-hot dispatch/combine einsums with a
   per-group capacity.  Simple and numerically exact w.r.t. capacity
@@ -10,10 +11,21 @@ Two dispatch strategies — the central §Perf lever for the MoE cells:
 * ``scatter`` — sort-based dispatch: tokens are scatter-added into per-expert
   capacity buffers, expert GEMMs run on the packed (E, C, D) buffer, results
   gather back.  No dispatch-matmul FLOPs; HLO FLOPs ≈ useful FLOPs.
+* ``ragged``  — the layer of one expert-parallel rank: it holds experts
+  ``moe.held`` of the ``n_experts`` the router scores, sorts the tokens'
+  choices of those experts by expert and runs one grouped matmul
+  (``lax.ragged_dot``) per projection.  No capacity: every choice of a held
+  expert is computed, and choices of experts held elsewhere add nothing
+  here (on one chip the exchange with the other ranks is absent).
 
-Both shard experts over the ``model`` axis (expert parallelism) and tokens
-over ``data``; the router runs in fp32.  Aux losses (load-balance + z-loss)
-are returned for the trainer.
+einsum and scatter shard experts over the ``model`` axis (expert
+parallelism) and tokens over ``data``.  The router runs in fp32 over every
+expert; its top-k weights are renormalised to sum to one when
+``moe.norm_topk_prob`` (the default; qwen3-moe, llama4-scout) and otherwise
+kept as the softmax gives them, scaled by ``moe.routed_scaling_factor``
+(DeepSeek-V2).  Shared experts are added once, on every rank.  Aux losses
+(load-balance + z-loss) are returned for the trainer; the ragged layer adds
+``expert_tokens``, the choices routed to each held expert.
 """
 from __future__ import annotations
 
@@ -30,9 +42,11 @@ from repro.models.param import ParamDef
 
 def moe_defs(cfg: ArchConfig) -> dict:
     m = cfg.moe
-    D, F, E = cfg.d_model, m.d_ff_expert, m.n_experts
+    if m.held is not None and m.dispatch != "ragged":
+        raise ValueError(f"moe.held needs dispatch 'ragged', not {m.dispatch!r}")
+    D, F, E = cfg.d_model, m.d_ff_expert, m.n_held
     defs = {
-        "router": ParamDef((D, E), ("embed", None), dtype="float32"),
+        "router": ParamDef((D, m.n_experts), ("embed", None), dtype="float32"),
         "wi_gate": ParamDef((E, D, F), ("experts", "embed", "expert_ff")),
         "wi_up": ParamDef((E, D, F), ("experts", "embed", "expert_ff")),
         "wo": ParamDef((E, F, D), ("experts", "expert_ff", "embed")),
@@ -49,12 +63,17 @@ def _capacity(tokens_per_group: int, cfg: ArchConfig) -> int:
 
 
 def _router(p: dict, xt: jax.Array, cfg: ArchConfig):
-    """xt (..., D) → probs/top-k (fp32) + aux losses."""
+    """xt (..., D) → top-k weights and experts (fp32) + aux losses.  The
+    weights sum to one with ``norm_topk_prob``; otherwise they are the
+    softmax's, times ``routed_scaling_factor``."""
     m = cfg.moe
     logits = jnp.einsum("...d,de->...e", xt.astype(jnp.float32), p["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, m.top_k)
-    top_p = top_p / jnp.maximum(jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
+    if m.norm_topk_prob:
+        top_p = top_p / jnp.maximum(jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
+    elif m.routed_scaling_factor != 1.0:
+        top_p = top_p * m.routed_scaling_factor
     # aux: load-balance (Switch) + router z-loss
     E = m.n_experts
     me = jnp.mean(probs, axis=tuple(range(probs.ndim - 1)))  # mean prob / expert
@@ -148,12 +167,47 @@ def _scatter_moe(p: dict, xt: jax.Array, cfg: ArchConfig):
 
 
 # ---------------------------------------------------------------------------
+# ragged (held experts, grouped matmul) dispatch
+# ---------------------------------------------------------------------------
+
+def _ragged_moe(p: dict, xt: jax.Array, cfg: ArchConfig):
+    """xt (T, D) flat tokens → the held experts' part of the routed output.
+
+    Every (token, choice) pair is sorted by held expert, pairs for experts
+    held elsewhere last; each projection is one ``ragged_dot`` over the
+    held experts' groups, so no choice is dropped and no held expert's
+    weights are read twice.  Rows past the groups are masked out."""
+    m = cfg.moe
+    T, D = xt.shape
+    K, n = m.top_k, m.n_held
+    first = 0 if m.held is None else m.held[0]
+
+    top_p, top_i, aux = _router(p, xt, cfg)  # (T,K) over all n_experts
+    local = top_i.reshape(-1) - first
+    mine = (local >= 0) & (local < n)
+    group = jnp.where(mine, local, n)  # n: held elsewhere
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((n + 1,), jnp.int32).at[group].add(1)[:n]
+    xs = xt[order // K]  # (T*K, D), grouped by held expert
+    g = jax.lax.ragged_dot(xs, p["wi_gate"], sizes)
+    u = jax.lax.ragged_dot(xs, p["wi_up"], sizes)
+    g = jax.nn.silu(g) if cfg.act == "swiglu" else jax.nn.gelu(g, approximate=True)
+    h = jax.lax.ragged_dot((g * u).astype(xt.dtype), p["wo"], sizes)  # (T*K, D)
+    h = h[jnp.argsort(order)].reshape(T, K, D)  # back to (token, choice)
+    w = jnp.where(mine.reshape(T, K), top_p, 0.0)
+    y = jnp.sum(jnp.where(mine.reshape(T, K, 1), h.astype(jnp.float32), 0.0) * w[..., None], axis=1)
+    aux["expert_tokens"] = sizes
+    return y.astype(xt.dtype), aux
+
+
+# ---------------------------------------------------------------------------
 # Public layer
 # ---------------------------------------------------------------------------
 
 def moe_apply(p: dict, x: jax.Array, cfg: ArchConfig):
     """x (B, L, D) → (y, aux).  Groups tokens for einsum dispatch so capacity
-    is local (≤4096 tokens per group), flattens for scatter dispatch."""
+    is local (≤4096 tokens per group), flattens for scatter and ragged
+    dispatch; the shared experts are added once."""
     m = cfg.moe
     B, L, D = x.shape
     T = B * L
@@ -163,8 +217,9 @@ def moe_apply(p: dict, x: jax.Array, cfg: ArchConfig):
         x_grp = x.reshape(G, g_tokens, D)
         y, aux = _einsum_moe(p, x_grp, cfg)
         y = y.reshape(B, L, D)
-    elif m.dispatch == "scatter":
-        y, aux = _scatter_moe(p, x.reshape(T, D), cfg)
+    elif m.dispatch in ("scatter", "ragged"):
+        fn = _scatter_moe if m.dispatch == "scatter" else _ragged_moe
+        y, aux = fn(p, x.reshape(T, D), cfg)
         y = y.reshape(B, L, D)
     else:
         raise ValueError(f"unknown moe dispatch {m.dispatch!r}")

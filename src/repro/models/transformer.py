@@ -7,12 +7,16 @@ differences are block *kinds*:
 * ``attn`` — pre-norm attention + gated MLP (dense / encoder / vlm)
 * ``mla``  — multi-head latent attention + MLP (minicpm3)
 * ``moe``  — attention + mixture-of-experts (qwen3-moe, llama4-scout)
+* ``mla_moe`` — latent attention + mixture-of-experts (DeepSeek-V2-Lite),
+  after ``moe.first_k_dense`` leading ``mla`` layers with a dense MLP
 * ``ssm``  — Mamba-2 SSD block (mamba2)
 * ``rec``  — RG-LRU recurrent block + MLP (recurrentgemma, with its
   (rec, rec, attn) pattern scanned as super-blocks)
 
 Compile hygiene: homogeneous stacks are ``lax.scan``-ed over a stacked
 parameter pytree (compile one layer, not 94) with a remat policy knob.
+Leading dense layers (``params["lead"]``) run before the scanned stack;
+the decode caches stay one stack over every layer.
 """
 from __future__ import annotations
 
@@ -57,10 +61,19 @@ def block_kind(cfg: ArchConfig) -> str:
     if cfg.family == "ssm":
         return "ssm"
     if cfg.family == "moe":
-        return "moe"
+        return "mla_moe" if cfg.mla is not None else "moe"
     if cfg.mla is not None:
         return "mla"
     return "attn"
+
+
+#: the kind of the leading dense layers in front of an expert stack
+_LEAD_KIND = {"moe": "attn", "mla_moe": "mla"}
+
+
+def n_lead(cfg: ArchConfig) -> int:
+    """Leading dense layers outside the scanned stack."""
+    return cfg.moe.first_k_dense if cfg.moe is not None else 0
 
 
 def block_defs(cfg: ArchConfig, kind: str) -> dict:
@@ -74,10 +87,10 @@ def block_defs(cfg: ArchConfig, kind: str) -> dict:
             "ln2": rmsnorm_def(D),
             "mlp": mlp_defs(D, cfg.d_ff, cfg.act),
         }
-    if kind == "moe":
+    if kind in ("moe", "mla_moe"):
         return {
             "ln1": rmsnorm_def(D),
-            "attn": attn_mod.attn_defs(cfg),
+            "attn": (mla_mod.mla_defs if kind == "mla_moe" else attn_mod.attn_defs)(cfg),
             "ln2": rmsnorm_def(D),
             "moe": moe_mod.moe_defs(cfg),
         }
@@ -120,21 +133,14 @@ def block_apply(
         x = x + h
         x = x + mlp_apply(p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg.act)
         return x, cache, aux
-    if kind == "mla":
-        h, cache = mla_mod.mla_apply(
-            p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps), positions, cfg,
-            causal=causal, want_cache=want_cache,
-        )
-        x = x + h
-        x = x + mlp_apply(p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg.act)
-        return x, cache, aux
-    # attn / moe
-    h, cache = attn_mod.attention_apply(
+    # attn / mla, then a dense MLP / moe
+    attn = mla_mod.mla_apply if kind in ("mla", "mla_moe") else attn_mod.attention_apply
+    h, cache = attn(
         p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps), positions, cfg,
         causal=causal, want_cache=want_cache,
     )
     x = x + h
-    if kind == "moe":
+    if kind in ("moe", "mla_moe"):
         h, aux = moe_mod.moe_apply(p["moe"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg)
     else:
         h = mlp_apply(p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg.act)
@@ -142,26 +148,29 @@ def block_apply(
 
 
 def block_decode(p: dict, x: jax.Array, cache, pos, cfg: ArchConfig, kind: str):
+    """Returns (y, cache, routed): ``routed`` is the choices routed to each
+    expert this layer holds (ragged expert layers), else None."""
     if kind == "ssm":
         h, cache = ssm_mod.ssm_decode_step(p["ssm"], rmsnorm(x, p["ln1"], cfg.norm_eps), cache, cfg)
-        return x + h, cache
+        return x + h, cache, None
     if kind == "rec":
         h, cache = rec_mod.rglru_decode_step(p["rec"], rmsnorm(x, p["ln1"], cfg.norm_eps), cache, cfg)
         x = x + h
-        return x + mlp_apply(p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg.act), cache
-    if kind == "mla":
+        return x + mlp_apply(p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg.act), cache, None
+    if kind in ("mla", "mla_moe"):
         h, cache = mla_mod.mla_decode_step(p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps), cache, pos, cfg)
-        x = x + h
-        return x + mlp_apply(p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg.act), cache
-    h, cache = attn_mod.attention_decode_step(
-        p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps), cache, pos, cfg
-    )
+    else:
+        h, cache = attn_mod.attention_decode_step(
+            p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps), cache, pos, cfg
+        )
     x = x + h
-    if kind == "moe":
-        h, _ = moe_mod.moe_apply(p["moe"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg)
+    routed = None
+    if kind in ("moe", "mla_moe"):
+        h, aux = moe_mod.moe_apply(p["moe"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg)
+        routed = aux.get("expert_tokens")
     else:
         h = mlp_apply(p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg.act)
-    return x + h, cache
+    return x + h, cache, routed
 
 
 def _block_constraint(cfg: ArchConfig, kind_or_defs) -> Any:
@@ -232,7 +241,10 @@ def model_defs(cfg: ArchConfig) -> dict:
             defs[f"tail_{i}"] = block_defs(hcfg, k)
     else:
         kind = block_kind(cfg)
-        defs["layers"] = stack_defs(block_defs(cfg, kind), cfg.n_layers)
+        lead = n_lead(cfg)
+        if lead:
+            defs["lead"] = stack_defs(block_defs(cfg, _LEAD_KIND[kind]), lead)
+        defs["layers"] = stack_defs(block_defs(cfg, kind), cfg.n_layers - lead)
     defs["final_norm"] = rmsnorm_def(D)
     return defs
 
@@ -344,26 +356,43 @@ def forward(params: dict, batch: dict, cfg: ArchConfig, *, want_cache: bool = Fa
         return x, caches_out, aux
 
     kind = block_kind(cfg)
+    lead = n_lead(cfg)
+    lead_caches = []
+    for li in range(lead):
+        lp = jax.tree.map(lambda t: t[li], params["lead"])
+        x, cache, _ = block_apply(
+            lp, x, positions, cfg, _LEAD_KIND[kind], causal=causal, want_cache=want_cache
+        )
+        lead_caches.append(cache)
     constrain = _block_constraint(cfg, kind)
 
     def layer_fn(x, lp):
         lp = constrain(lp)
         y, cache, aux = block_apply(lp, x, positions, cfg, kind, causal=causal, want_cache=want_cache)
-        return y, (cache, aux)
+        routed = aux.pop("expert_tokens", None)  # per layer, never summed
+        return y, (cache, aux, routed)
 
     body = _remat(layer_fn, cfg)
     if cfg.scan_layers:
-        x, (caches, auxs) = jax.lax.scan(lambda c, lp: body(c, lp), x, params["layers"])
+        x, (caches, auxs, routed) = jax.lax.scan(lambda c, lp: body(c, lp), x, params["layers"])
         aux = jax.tree.map(jnp.sum, auxs)
     else:
-        caches_l, aux = [], _zero_aux()
-        for li in range(cfg.n_layers):
+        caches_l, routed_l, aux = [], [], _zero_aux()
+        for li in range(cfg.n_layers - lead):
             lp = jax.tree.map(lambda t: t[li], params["layers"])
-            x, (cache, a) = body(x, lp)
+            x, (cache, a, r) = body(x, lp)
             caches_l.append(cache)
+            routed_l.append(r)
             aux = jax.tree.map(lambda u, v: u + v, aux, a)
         caches = (
             jax.tree.map(lambda *xs: jnp.stack(xs), *caches_l) if want_cache else None
+        )
+        routed = None if routed_l[0] is None else jnp.stack(routed_l)
+    if routed is not None:
+        aux["expert_tokens"] = routed  # (expert layers, experts held)
+    if want_cache and lead:  # one stack over every layer, leading ones first
+        caches = jax.tree.map(
+            lambda c, *ls: jnp.concatenate([jnp.stack(ls), c]), caches, *lead_caches
         )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return x, (caches if want_cache else None), aux
@@ -401,22 +430,64 @@ def loss_fn(params: dict, batch: dict, cfg: ArchConfig):
     return total, metrics
 
 
-def prefill(params: dict, batch: dict, cfg: ArchConfig):
+def prefill(params: dict, batch: dict, cfg: ArchConfig, *, counts: bool = False):
     """→ (last-token logits (B,1,V), caches).  Only the final position's
-    logits are computed (memory discipline for 32k×150k-vocab prefill)."""
-    x, caches, _ = forward(params, batch, cfg, want_cache=True)
+    logits are computed (memory discipline for 32k×150k-vocab prefill).
+    ``counts`` adds a third output: the choices routed to each held expert
+    per expert layer, (expert layers, experts held) int32, or None where no
+    layer counts them."""
+    x, caches, aux = forward(params, batch, cfg, want_cache=True)
     x_last = x[:, -1:]
     if cfg.frontend == "audio":
         logits = jnp.einsum("bld,dv->blv", x_last, params["head"])
     else:
         logits = logits_apply(params, x_last, cfg)
+    if counts:
+        return logits, caches, aux.get("expert_tokens")
     return logits, caches
 
 
-def decode_step(params: dict, tokens: jax.Array, caches, pos, cfg: ArchConfig):
+def _decode_lead_and_stack(params: dict, x, caches, pos, cfg: ArchConfig, kind: str):
+    """Decode through the leading dense layers, then the scanned stack,
+    with the caches kept as one stack over every layer: the scan carries
+    that stack, reads each layer's rows from it and writes them back in
+    place, so no copy of the whole cache is sliced off or concatenated."""
+    lead = n_lead(cfg)
+    for li in range(lead):
+        lp = jax.tree.map(lambda t: t[li], params["lead"])
+        x, c, _ = block_decode(lp, x, jax.tree.map(lambda t: t[li], caches), pos, cfg,
+                               _LEAD_KIND[kind])
+        caches = jax.tree.map(lambda t, u: t.at[li].set(u), caches, c)
+    constrain = _block_constraint(cfg, kind)
+
+    def layer_fn(carry, inp):
+        x, caches = carry
+        lp, li = inp
+        c = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(t, li, keepdims=False), caches)
+        x, c, routed = block_decode(constrain(lp), x, c, pos, cfg, kind)
+        caches = jax.tree.map(lambda t, u: jax.lax.dynamic_update_index_in_dim(t, u, li, 0),
+                              caches, c)
+        return (x, caches), routed
+
+    idx = jnp.arange(lead, cfg.n_layers, dtype=jnp.int32)
+    if cfg.scan_layers:
+        (x, caches), routed = jax.lax.scan(layer_fn, (x, caches), (params["layers"], idx))
+        return x, caches, routed
+    routed_l = []
+    for li in range(cfg.n_layers - lead):
+        lp = jax.tree.map(lambda t: t[li], params["layers"])
+        (x, caches), r = layer_fn((x, caches), (lp, idx[li]))
+        routed_l.append(r)
+    return x, caches, None if routed_l[0] is None else jnp.stack(routed_l)
+
+
+def decode_step(params: dict, tokens: jax.Array, caches, pos, cfg: ArchConfig, *,
+                counts: bool = False):
     """One decode step.  tokens (B,1) int32; pos scalar int32 (current
-    position).  → (logits (B,1,V), new caches)."""
+    position).  → (logits (B,1,V), new caches); ``counts`` adds the choices
+    routed to each held expert per expert layer, as :func:`prefill` does."""
     x = embed_apply(params, tokens, cfg)
+    routed = None
 
     if cfg.family == "hybrid":
         hcfg = _hybrid_window_cfg(cfg)
@@ -427,7 +498,7 @@ def decode_step(params: dict, tokens: jax.Array, caches, pos, cfg: ArchConfig):
             lp, cache = inp
             new = {}
             for i, k in enumerate(pat):
-                x, c = block_decode(lp[f"{k}_{i}"], x, cache[f"{k}_{i}"], pos, hcfg, k)
+                x, c, _ = block_decode(lp[f"{k}_{i}"], x, cache[f"{k}_{i}"], pos, hcfg, k)
                 new[f"{k}_{i}"] = c
             return x, new
 
@@ -442,16 +513,20 @@ def decode_step(params: dict, tokens: jax.Array, caches, pos, cfg: ArchConfig):
             new_scan = jax.tree.map(lambda *xs: jnp.stack(xs), *new_l)
         new_tail = []
         for i, k in enumerate(rem):
-            x, c = block_decode(params[f"tail_{i}"], x, caches["tail"][i], pos, hcfg, k)
+            x, c, _ = block_decode(params[f"tail_{i}"], x, caches["tail"][i], pos, hcfg, k)
             new_tail.append(c)
         new_caches = {"scan": new_scan, "tail": new_tail}
+    elif n_lead(cfg):
+        x, new_caches, routed = _decode_lead_and_stack(
+            params, x, caches, pos, cfg, block_kind(cfg)
+        )
     else:
         kind = block_kind(cfg)
         constrain = _block_constraint(cfg, kind)
 
         def layer_fn(x, inp):
             lp, cache = inp
-            y, c = block_decode(constrain(lp), x, cache, pos, cfg, kind)
+            y, c, _ = block_decode(constrain(lp), x, cache, pos, cfg, kind)
             return y, c
 
         if cfg.scan_layers:
@@ -466,6 +541,8 @@ def decode_step(params: dict, tokens: jax.Array, caches, pos, cfg: ArchConfig):
 
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = logits_apply(params, x, cfg)
+    if counts:
+        return logits, new_caches, routed
     return logits, new_caches
 
 
@@ -517,7 +594,7 @@ def _cache_defs_for_kind(cfg: ArchConfig, kind: str, batch: int, max_seq: int) -
             "h": ParamDef((batch, W), ("batch", "ff"), dtype="float32"),
             "conv": ParamDef((batch, cfg.hybrid.conv_width - 1, W), ("batch", None, "ff"), dtype=cfg.dtype),
         }
-    if kind == "mla":
+    if kind in ("mla", "mla_moe"):
         shapes = mla_mod.mla_cache_shapes(cfg, batch, max_seq)
         return {k: ParamDef(sh, ax, dtype=cfg.dtype) for k, (sh, ax) in shapes.items()}
     # attention (ring-buffered if windowed)

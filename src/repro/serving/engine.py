@@ -92,7 +92,7 @@ def _jitted_steps(cfg):
     if fns is None:
 
         def serve_decode(p, t, c, pos):
-            return decode_step(p, t, c, pos, cfg)
+            return decode_step(p, t, c, pos, cfg, counts=True)
 
         def serve_prefill(p, b):
             return prefill(p, b, cfg)
@@ -112,8 +112,8 @@ def _jitted_serve_ops(cfg, max_seq: int):
     if fns is None:
 
         def prefill_prime(p, b):
-            logits, caches = prefill(p, b, cfg)
-            return logits[:, -1], prime_cache(cfg, caches, b["tokens"].shape[1], max_seq)
+            logits, caches, routed = prefill(p, b, cfg, counts=True)
+            return logits[:, -1], prime_cache(cfg, caches, b["tokens"].shape[1], max_seq), routed
 
         def install(full, one, tok, slot, pending):
             caches = jax.tree.map(
@@ -226,16 +226,31 @@ class Request:
 # Codelets (``eng`` is the ServeEngine, bound as a static parameter).
 # ---------------------------------------------------------------------------
 
+def _count_routed(sp, routed) -> None:
+    """Attach a program's routed-choice counts ((expert layers, experts
+    held) int32, on the device) to span ``sp``.  Called only while spans
+    are recorded, so the untimed path never reads them on the host."""
+    if sp is None or routed is None:
+        return
+    with trace.span("serve.device_wait"):
+        n = np.asarray(routed)
+    sp.counts.update(expert_tokens=int(n.sum()), expert_tokens_max=int(n.max()),
+                     experts_held=n.shape[-1])
+
+
 @sp_task(write=("state",), name="serve.decode", cost=10.0)
 def _decode_codelet(state, *, eng):
     if not eng._slot_req:
         return
     st = state.value
-    logits, new_caches = eng._decode(
+    # the decode program returns (logits, caches, routed-choice counts);
+    # a stand-in program may return the first two alone
+    logits, new_caches, *routed = eng._decode(
         eng.params, st["tok"], st["caches"], jnp.asarray(eng._pos)
     )
     toks = eng._sample_batch(logits[:, 0])
-    state.value = {"caches": new_caches, "tok": toks[:, None]}
+    state.value = {"caches": new_caches, "tok": toks[:, None],
+                   "routed": routed[0] if routed else None}
 
 
 @sp_task(read=("state",), name="serve.collect")
@@ -246,6 +261,7 @@ def _collect_codelet(state, *, eng):
     eng._last_tok = state["tok"]
     with trace.span("serve.device_wait"):
         toks = np.asarray(state["tok"])[:, 0]
+    _count_routed(trace.current(), state.get("routed"))
     now = time.perf_counter()
     for slot in sorted(eng._slot_req):
         req = eng._slot_req.get(slot)
@@ -290,8 +306,9 @@ def _prefill_codelet(out, *, eng, req, sample_first):
     if sp is not None:
         sp.req = req.req_id
         sp.counts["prompt_len"] = prompt.shape[1]
-    logits_last, primed = eng._prefill_prime(eng.params, {"tokens": jnp.asarray(prompt)})
+    logits_last, primed, routed = eng._prefill_prime(eng.params, {"tokens": jnp.asarray(prompt)})
     first = eng._sample_one(req, logits_last[0]) if sample_first else None
+    _count_routed(sp, routed)
     out.value = (primed, first, prompt.shape[1])
 
 

@@ -205,7 +205,7 @@ class SpecDecoder:
         if n >= 1:
             full = [int(t) for t in req.prompt] + [int(t) for t in req.out_tokens]
             toks = np.asarray(full[:n], np.int32)[None, :]
-            _, primed = self._prime(self.params, {"tokens": jnp.asarray(toks)})
+            _, primed, _ = self._prime(self.params, {"tokens": jnp.asarray(toks)})
             self._caches, _ = self._install(
                 self._caches, primed, self._dummy_tok, jnp.int32(slot), jnp.int32(0)
             )
@@ -265,7 +265,7 @@ class SpecDecoder:
             s.last_tok = t
             toks[slot, 0] = t
             pos[slot] = min(p, eng.max_seq - 1)
-        logits, self._caches = self._decode(
+        logits, self._caches, _ = self._decode(
             self.params, jnp.asarray(toks), self._caches, jnp.asarray(pos)
         )
         self.draft_feeds += 1

@@ -156,3 +156,45 @@ def test_writeback_gather_fits_one_chip(one_chip, smoke_cfg):
     assert chunk <= ma.output_size_in_bytes <= chunk + 4096  # and the output tuple's table
     assert ma.temp_size_in_bytes < chunk
     _fits(compiled)
+
+
+@pytest.fixture(scope="module")
+def v2_share():
+    """The DeepSeek-V2-Lite share of the benchmark's ``dsv2l.docqa`` cell:
+    27 layers, 8 of 64 experts held, 32 slots x 3072."""
+    return get_config("deepseek-v2-lite-ep8"), 32, 3072
+
+
+def test_deepseek_v2_lite_decode_updates_the_latent_cache_in_place(one_chip, v2_share):
+    """The expert layers' grouped matmuls compile for the chip, and the
+    decode step writes the cache it is donated in place: its temporaries are
+    far below one layer's latent rows."""
+    from repro.models import abstract_cache, abstract_params
+    from repro.serving.engine import _jitted_steps
+
+    cfg, n, S = v2_share
+    decode, _ = _jitted_steps(cfg)
+    caches = abstract_cache(cfg, n, S)
+    compiled = decode.lower(
+        _on(one_chip, abstract_params(cfg)), _sds((n, 1), "int32", one_chip),
+        _on(one_chip, caches), _sds((n,), "int32", one_chip),
+    ).compile()
+    cache = sum(math.prod(x.shape) * x.dtype.itemsize for x in jax.tree.leaves(caches))
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == cache
+    layer = cache // cfg.n_layers
+    assert ma.temp_size_in_bytes < layer // 8
+    assert "tpu_custom_call" in compiled.as_text()  # the grouped matmul kernel
+    _fits(compiled)
+
+
+def test_deepseek_v2_lite_prefill_fits_one_chip(one_chip, v2_share):
+    from repro.models import abstract_params
+    from repro.serving.engine import _jitted_serve_ops
+
+    cfg, _, S = v2_share
+    prefill_prime, _ = _jitted_serve_ops(cfg, S)
+    compiled = prefill_prime.lower(
+        _on(one_chip, abstract_params(cfg)), {"tokens": _sds((1, 2560), "int32", one_chip)},
+    ).compile()
+    _fits(compiled)

@@ -228,3 +228,71 @@ def test_buffer_counts_every_span_appended_from_many_threads():
         sys.setswitchinterval(old)
     assert not any(t.is_alive() for t in threads)
     assert len(buf) == 100 and buf.dropped == n_threads * per_thread - 100
+
+
+class _ReadSpy:
+    """Stands in for a program's routed-choice counts and counts the host
+    reads of them."""
+
+    def __init__(self, a):
+        self.a, self.reads = a, 0
+
+    def __array__(self, dtype=None, copy=None):
+        self.reads += 1
+        return np.asarray(self.a, dtype)
+
+
+def _routed_engine():
+    """A reduced DeepSeek-V2-Lite share (2 of 8 experts held, 2 expert
+    layers) whose programs' counts pass through read spies."""
+    cfg = reduced_config("deepseek-v2-lite-ep8").replace(n_layers=3)
+    eng = ServeEngine(cfg, init_params(jax.random.PRNGKey(0), cfg), n_slots=4, max_seq=MAX_SEQ,
+                      block_size=BLOCK)
+    spies = []
+    decode, prefill_prime = eng._decode, eng._prefill_prime
+
+    def spy(fn):
+        def wrapped(*a):
+            *out, routed = fn(*a)
+            spies.append(_ReadSpy(routed))
+            return (*out, spies[-1])
+        return wrapped
+
+    eng._decode, eng._prefill_prime = spy(decode), spy(prefill_prime)
+    return cfg, eng, spies
+
+
+def test_collect_and_prefill_spans_carry_the_routed_choice_counts(tmp_path):
+    cfg, eng, spies = _routed_engine()
+    with eng:
+        _drive(eng, cfg, 1)  # compile outside the session
+        mark = trace.Span("mark").sid
+        with jax.profiler.trace(str(tmp_path)):
+            reqs = _drive(eng, cfg, 2)
+        spans = [s for s in trace.SPANS.snapshot() if s.sid > mark]
+    assert all(r.done for r in reqs)
+    counted = {n: [s for s in spans if s.name == n] for n in ("serve.collect", "serve.prefill")}
+    read = [np.asarray(s.a) for s in spies if s.reads]
+    assert counted["serve.collect"] and counted["serve.prefill"]
+    assert len(read) == sum(len(v) for v in counted.values())
+    moe_layers, held = cfg.n_layers - cfg.moe.first_k_dense, cfg.moe.held[1]
+    for name, got in counted.items():
+        for s in got:
+            assert s.counts["experts_held"] == held
+            assert 0 <= s.counts["expert_tokens_max"] <= s.counts["expert_tokens"]
+            n_tok = s.counts["prompt_len"] if name == "serve.prefill" else 4  # at most 4 slots
+            assert s.counts["expert_tokens"] <= moe_layers * n_tok * min(cfg.moe.top_k, held)
+    assert sorted((int(a.sum()), int(a.max())) for a in read) == sorted(
+        (s.counts["expert_tokens"], s.counts["expert_tokens_max"])
+        for v in counted.values() for s in v)
+
+
+def test_routed_choice_counts_are_never_read_on_the_host_while_nothing_records(recorded):
+    cfg, eng, spies = _routed_engine()
+    assert not trace.profiling()
+    with eng:
+        reqs = _drive(eng, cfg, 3)
+    assert all(r.done for r in reqs)
+    assert spies and all(s.reads == 0 for s in spies)
+    # a model without a counted expert layer attaches no counts
+    assert not any("expert_tokens" in s.counts for s in recorded[0])
