@@ -34,10 +34,10 @@ ROOT = Path(__file__).resolve().parent
 sys.path.insert(0, str(ROOT / "src"))
 
 ARCH = "deepseek-7b"
-# One chip (16 GB): weights + KV cache + the decode program's temporaries
-# must stay under ~12 GB.  8 layers, 8 slots x 2048 positions reckon at
-# 4.9 GB of weights, 2.1 GB of cache and ~2.1 GB of decode temporaries
-# (the one-hot KV update rewrites the whole cache).
+# One chip (16 GB): weights + KV cache must stay under ~12 GB.  8 layers,
+# 8 slots x 2048 positions reckon at 4.9 GB of weights and 2.1 GB of cache;
+# decode writes its rows into the cache in place, with no cache-sized
+# temporaries.
 SERVE = dict(
     n_layers=8, n_slots=8, max_seq=2048, block_size=16,
     n_requests=16, prompt_lens=(64, 128), gen=(32, 40, 48),
@@ -222,8 +222,7 @@ def serve_phase(
     )
     print(
         f"[smoke] reckoned: weights {w_bytes / 1e9:.3f} GB + KV cache {c_bytes / 1e9:.3f} GB "
-        f"+ decode temporaries ~{c_bytes / 1e9:.3f} GB (one-hot KV update) = "
-        f"{(w_bytes + 2 * c_bytes) / 1e9:.3f} GB",
+        f"= {(w_bytes + c_bytes) / 1e9:.3f} GB",
         flush=True,
     )
     t0 = time.perf_counter()

@@ -424,27 +424,47 @@ def kv_cache_axes(cfg: ArchConfig = None) -> tuple:
     return ("batch", "kv_seq", "kv_heads", None)
 
 
-def kv_cache_update(
-    cache: jax.Array, new: jax.Array, slot: jax.Array, strategy: str = "onehot"
-) -> jax.Array:
-    """Write ``new`` (B, 1, KH, Dh) at ``slot`` into the S-dim-sharded cache.
-    ``slot`` may be a traced scalar or a per-sequence (B,) vector
-    (continuous batching: sequences at different positions).
+def seq_sharded(axes: tuple) -> bool:
+    """Whether a decode cache laid out as ``axes`` splits its sequence
+    dimension over more than one device of the active mesh."""
+    from repro.dist.sharding import current_mesh
 
-    * ``onehot``: cache·(1−δ) + new·δ — fully shardable select; writes the
-      whole cache (bandwidth-inflated baseline).
-    * ``dus``: dynamic-update-slice on the sequence dim (scalar slot only);
-      relies on the SPMD partitioner's DUS handling (the §Perf alternative).
+    mesh = current_mesh()
+    return axes[1] == "kv_seq" and mesh is not None and mesh.shape.get("model", 1) > 1
+
+
+def layer_of(cache: jax.Array, layer) -> jax.Array:
+    """Layer ``layer`` of a cache stack, or the cache itself for None."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
+
+
+def kv_cache_update(
+    cache: jax.Array, new: jax.Array, slot: jax.Array, axes: tuple, layer=None
+) -> jax.Array:
+    """Write each sequence's new row ``new[b, 0]`` at position ``slot[b]``
+    (``slot`` a scalar or a (B,) vector) of a cache (B, S, ...) laid out as
+    ``axes``, or of layer ``layer`` of a stack (L, B, S, ...), in place.
+
+    The B rows are one scatter into the cache, so a step reads and writes
+    only its own rows; a slot outside [0, S) writes nothing.  A cache whose
+    sequence dimension is split over devices takes the select
+    cache·(1−δ) + new·δ over its layer instead, which every shard computes
+    locally.
     """
-    slot = jnp.asarray(slot)
-    if strategy == "dus" and slot.ndim == 0:
-        return jax.lax.dynamic_update_slice_in_dim(cache, new.astype(cache.dtype), slot, axis=1)
-    S = cache.shape[1]
-    if slot.ndim == 0:
-        oh = (jnp.arange(S) == slot).astype(cache.dtype)[None, :, None, None]
-    else:  # per-sequence slots
-        oh = (jnp.arange(S)[None, :] == slot[:, None]).astype(cache.dtype)[:, :, None, None]
-    return cache * (1 - oh) + new.astype(cache.dtype) * oh
+    B = new.shape[0]
+    slot = jnp.broadcast_to(jnp.asarray(slot, jnp.int32), (B,))
+    rows = new[:, 0].astype(cache.dtype)
+    if not seq_sharded(axes):
+        idx = (jnp.arange(B), slot) if layer is None else (layer, jnp.arange(B), slot)
+        return cache.at[idx].set(rows, mode="drop", indices_are_sorted=True,
+                                 unique_indices=True, wrap_negative_indices=False)
+    c = layer_of(cache, layer)
+    oh = (jnp.arange(c.shape[1])[None, :] == slot[:, None]).astype(c.dtype)
+    oh = oh.reshape(oh.shape + (1,) * (c.ndim - 2))
+    c = c * (1 - oh) + rows[:, None] * oh
+    return c if layer is None else jax.lax.dynamic_update_index_in_dim(cache, c, layer, 0)
 
 
 def decode_attention(
@@ -489,8 +509,10 @@ def attention_decode_step(
     cache: dict,
     pos: jax.Array,
     cfg: ArchConfig,
+    layer=None,
 ):
-    """x: (B, 1, D) new-token activations; cache: {'k','v'} ring or full.
+    """x: (B, 1, D) new-token activations; cache: {'k','v'} ring or full,
+    or the stacks over every layer with ``layer`` the index of this one.
 
     Returns (out (B,1,D), new_cache).
     """
@@ -498,11 +520,13 @@ def attention_decode_step(
         jnp.asarray(pos, jnp.int32).reshape(-1, 1), (x.shape[0], 1)
     )
     q, k, v = qkv_project(p, x, positions, cfg)
-    S = cache["k"].shape[1]
+    S = cache["k"].shape[-3]
     slot = pos % S if cfg.attn_window is not None else pos
-    k_cache = kv_cache_update(cache["k"], k, slot, cfg.kv_update)
-    v_cache = kv_cache_update(cache["v"], v, slot, cfg.kv_update)
-    out = decode_attention(q, k_cache, v_cache, pos, window=cfg.attn_window)
+    axes = kv_cache_axes(cfg)
+    k_cache = kv_cache_update(cache["k"], k, slot, axes, layer)
+    v_cache = kv_cache_update(cache["v"], v, slot, axes, layer)
+    out = decode_attention(q, layer_of(k_cache, layer), layer_of(v_cache, layer), pos,
+                           window=cfg.attn_window)
     out = jnp.einsum("blhk,hkd->bld", out, p["wo"])
     return out, {"k": k_cache, "v": v_cache}
 

@@ -143,10 +143,9 @@ class ArchConfig:
     logits_chunk: Optional[int] = None  # chunked cross-entropy over sequence
     optimizer: str = "adamw"  # adamw | adafactor
     opt_state_dtype: str = "float32"
-    kv_update: str = "onehot"  # onehot | dus
     # decode KV cache layout: 'seq' shards the cache sequence dim over the
     # model axis (flash-decoding combine); 'heads' shards kv heads instead
-    # (local updates — pairs with kv_update='dus'; needs n_kv % model == 0)
+    # (needs n_kv % model == 0)
     kv_shard: str = "seq"
     # embedding/logits tables are allocated padded to this multiple so the
     # vocab dim shards on any mesh (Megatron-style vocab padding); pad

@@ -24,7 +24,7 @@ from repro.dist.sharding import shard
 from repro.models.config import ArchConfig
 from repro.models.layers import apply_rope, rmsnorm, yarn_frequencies, yarn_mscale
 from repro.models.param import ParamDef
-from repro.models.attention import full_attention, kv_cache_update
+from repro.models.attention import full_attention, kv_cache_update, layer_of
 
 NEG_INF = -1e30
 
@@ -122,29 +122,35 @@ def mla_apply(
     return y, None
 
 
+#: logical axes of both latent cache leaves: sequence-sharded like GQA caches
+MLA_CACHE_AXES = ("batch", "kv_seq", None)
+
+
 def mla_cache_shapes(cfg: ArchConfig, batch: int, max_seq: int) -> dict:
     m = cfg.mla
     return {
-        "c_kv": ((batch, max_seq, m.kv_lora_rank), ("batch", "kv_seq", None)),
-        "k_rope": ((batch, max_seq, m.qk_rope_head_dim), ("batch", "kv_seq", None)),
+        "c_kv": ((batch, max_seq, m.kv_lora_rank), MLA_CACHE_AXES),
+        "k_rope": ((batch, max_seq, m.qk_rope_head_dim), MLA_CACHE_AXES),
     }
 
 
-def mla_decode_step(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg: ArchConfig):
-    """Absorbed-matmul decode.  x (B,1,D); cache {'c_kv': (B,S,r), 'k_rope': (B,S,d_r)}."""
+def mla_decode_step(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg: ArchConfig,
+                    layer=None):
+    """Absorbed-matmul decode.  x (B,1,D); cache {'c_kv': (B,S,r), 'k_rope':
+    (B,S,d_r)}, or the stacks over every layer with ``layer`` this one's."""
     m = cfg.mla
     B = x.shape[0]
     positions = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1, 1), (B, 1))
     q_nope, q_rope = _project_q(p, x, positions, cfg)  # (B,1,H,·)
     c_kv_new, k_rope_new = _project_kv_latent(p, x, positions, cfg)
 
-    S = cache["c_kv"].shape[1]
-    c_cache = kv_cache_update(
-        cache["c_kv"][:, :, None, :], c_kv_new[:, :, None, :], pos, cfg.kv_update
-    )[:, :, 0, :]
-    r_cache = kv_cache_update(
-        cache["k_rope"][:, :, None, :], k_rope_new[:, :, None, :], pos, cfg.kv_update
-    )[:, :, 0, :]
+    new_cache = {
+        "c_kv": kv_cache_update(cache["c_kv"], c_kv_new, pos, MLA_CACHE_AXES, layer),
+        "k_rope": kv_cache_update(cache["k_rope"], k_rope_new, pos, MLA_CACHE_AXES, layer),
+    }
+    c_cache = layer_of(new_cache["c_kv"], layer)
+    r_cache = layer_of(new_cache["k_rope"], layer)
+    S = c_cache.shape[1]
 
     # absorb W_UK into q: q_lat (B,H,r_kv)
     q_lat = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], p["wk_b"])
@@ -160,4 +166,4 @@ def mla_decode_step(p: dict, x: jax.Array, cache: dict, pos: jax.Array, cfg: Arc
     lat_out = jnp.einsum("bhs,bsr->bhr", w, c_cache.astype(jnp.float32))
     v_out = jnp.einsum("bhr,rhv->bhv", lat_out.astype(x.dtype), p["wv_b"])
     y = jnp.einsum("bhv,hvd->bd", v_out, p["wo"])[:, None]
-    return y, {"c_kv": c_cache, "k_rope": r_cache}
+    return y, new_cache

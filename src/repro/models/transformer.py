@@ -147,22 +147,24 @@ def block_apply(
     return x + h, cache, aux
 
 
-def block_decode(p: dict, x: jax.Array, cache, pos, cfg: ArchConfig, kind: str):
+def block_decode(p: dict, x: jax.Array, cache, pos, cfg: ArchConfig, kind: str, layer=None):
     """Returns (y, cache, routed): ``routed`` is the choices routed to each
-    expert this layer holds (ragged expert layers), else None."""
-    if kind == "ssm":
-        h, cache = ssm_mod.ssm_decode_step(p["ssm"], rmsnorm(x, p["ln1"], cfg.norm_eps), cache, cfg)
-        return x + h, cache, None
-    if kind == "rec":
-        h, cache = rec_mod.rglru_decode_step(p["rec"], rmsnorm(x, p["ln1"], cfg.norm_eps), cache, cfg)
+    expert this layer holds (ragged expert layers), else None.  With
+    ``layer``, ``cache`` is the stack over every layer and this block reads
+    and writes layer ``layer`` of it in place."""
+    if kind in ("ssm", "rec"):
+        step = ssm_mod.ssm_decode_step if kind == "ssm" else rec_mod.rglru_decode_step
+        h, c = step(p[kind], rmsnorm(x, p["ln1"], cfg.norm_eps),
+                    jax.tree.map(lambda t: attn_mod.layer_of(t, layer), cache), cfg)
+        if layer is not None:
+            c = jax.tree.map(lambda t, u: jax.lax.dynamic_update_index_in_dim(t, u, layer, 0),
+                             cache, c)
         x = x + h
-        return x + mlp_apply(p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg.act), cache, None
-    if kind in ("mla", "mla_moe"):
-        h, cache = mla_mod.mla_decode_step(p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps), cache, pos, cfg)
-    else:
-        h, cache = attn_mod.attention_decode_step(
-            p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps), cache, pos, cfg
-        )
+        if kind == "ssm":
+            return x, c, None
+        return x + mlp_apply(p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg.act), c, None
+    attn = mla_mod.mla_decode_step if kind in ("mla", "mla_moe") else attn_mod.attention_decode_step
+    h, cache = attn(p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps), cache, pos, cfg, layer)
     x = x + h
     routed = None
     if kind in ("moe", "mla_moe"):
@@ -447,26 +449,22 @@ def prefill(params: dict, batch: dict, cfg: ArchConfig, *, counts: bool = False)
     return logits, caches
 
 
-def _decode_lead_and_stack(params: dict, x, caches, pos, cfg: ArchConfig, kind: str):
+def _decode_stack(params: dict, x, caches, pos, cfg: ArchConfig, kind: str):
     """Decode through the leading dense layers, then the scanned stack,
-    with the caches kept as one stack over every layer: the scan carries
-    that stack, reads each layer's rows from it and writes them back in
-    place, so no copy of the whole cache is sliced off or concatenated."""
+    with the caches kept as one stack over every layer that the loop
+    carries: each layer writes its new rows into its own layer of the stack
+    and reads that layer in place, so no copy of a layer or of the whole
+    cache is made."""
     lead = n_lead(cfg)
     for li in range(lead):
         lp = jax.tree.map(lambda t: t[li], params["lead"])
-        x, c, _ = block_decode(lp, x, jax.tree.map(lambda t: t[li], caches), pos, cfg,
-                               _LEAD_KIND[kind])
-        caches = jax.tree.map(lambda t, u: t.at[li].set(u), caches, c)
+        x, caches, _ = block_decode(lp, x, caches, pos, cfg, _LEAD_KIND[kind], li)
     constrain = _block_constraint(cfg, kind)
 
     def layer_fn(carry, inp):
         x, caches = carry
         lp, li = inp
-        c = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(t, li, keepdims=False), caches)
-        x, c, routed = block_decode(constrain(lp), x, c, pos, cfg, kind)
-        caches = jax.tree.map(lambda t, u: jax.lax.dynamic_update_index_in_dim(t, u, li, 0),
-                              caches, c)
+        x, caches, routed = block_decode(constrain(lp), x, caches, pos, cfg, kind, li)
         return (x, caches), routed
 
     idx = jnp.arange(lead, cfg.n_layers, dtype=jnp.int32)
@@ -516,28 +514,8 @@ def decode_step(params: dict, tokens: jax.Array, caches, pos, cfg: ArchConfig, *
             x, c, _ = block_decode(params[f"tail_{i}"], x, caches["tail"][i], pos, hcfg, k)
             new_tail.append(c)
         new_caches = {"scan": new_scan, "tail": new_tail}
-    elif n_lead(cfg):
-        x, new_caches, routed = _decode_lead_and_stack(
-            params, x, caches, pos, cfg, block_kind(cfg)
-        )
     else:
-        kind = block_kind(cfg)
-        constrain = _block_constraint(cfg, kind)
-
-        def layer_fn(x, inp):
-            lp, cache = inp
-            y, c, _ = block_decode(constrain(lp), x, cache, pos, cfg, kind)
-            return y, c
-
-        if cfg.scan_layers:
-            x, new_caches = jax.lax.scan(layer_fn, x, (params["layers"], caches))
-        else:
-            new_l = []
-            for li in range(cfg.n_layers):
-                inp = jax.tree.map(lambda t: t[li], (params["layers"], caches))
-                x, c = layer_fn(x, inp)
-                new_l.append(c)
-            new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *new_l)
+        x, new_caches, routed = _decode_stack(params, x, caches, pos, cfg, block_kind(cfg))
 
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = logits_apply(params, x, cfg)

@@ -165,14 +165,14 @@ def v2_share():
     return get_config("deepseek-v2-lite-ep8"), 32, 3072
 
 
-def test_deepseek_v2_lite_decode_updates_the_latent_cache_in_place(one_chip, v2_share):
-    """The expert layers' grouped matmuls compile for the chip, and the
-    decode step writes the cache it is donated in place: its temporaries are
-    far below one layer's latent rows."""
+def _decode_in_place(one_chip, cfg, n: int, S: int):
+    """Compile the engine's decode step (per-slot positions, cache donated)
+    and check that it writes the cache it is donated in place: the whole
+    cache aliased, and temporaries far below one layer's rows, so neither
+    a copy of the cache nor a per-layer copy of it is made."""
     from repro.models import abstract_cache, abstract_params
     from repro.serving.engine import _jitted_steps
 
-    cfg, n, S = v2_share
     decode, _ = _jitted_steps(cfg)
     caches = abstract_cache(cfg, n, S)
     compiled = decode.lower(
@@ -184,8 +184,21 @@ def test_deepseek_v2_lite_decode_updates_the_latent_cache_in_place(one_chip, v2_
     assert ma.alias_size_in_bytes == cache
     layer = cache // cfg.n_layers
     assert ma.temp_size_in_bytes < layer // 8
-    assert "tpu_custom_call" in compiled.as_text()  # the grouped matmul kernel
     _fits(compiled)
+    return compiled
+
+
+def test_deepseek_7b_decode_writes_its_rows_into_the_cache_in_place(one_chip, smoke_cfg):
+    """deepseek-7b at the benchmark's serving cells' sizes: 8 layers, 16
+    slots x 2048."""
+    _decode_in_place(one_chip, smoke_cfg, 16, 2048)
+
+
+def test_deepseek_v2_lite_decode_updates_the_latent_cache_in_place(one_chip, v2_share):
+    """The expert layers' grouped matmuls compile for the chip, and the
+    decode step writes the latent cache it is donated in place."""
+    compiled = _decode_in_place(one_chip, *v2_share)
+    assert "tpu_custom_call" in compiled.as_text()  # the grouped matmul kernel
 
 
 def test_deepseek_v2_lite_prefill_fits_one_chip(one_chip, v2_share):

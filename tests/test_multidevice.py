@@ -142,3 +142,53 @@ def test_hierarchical_psum_matches_flat():
     )
     assert r.returncode == 0, f"stdout:\n{r.stdout[-2000:]}\nstderr:\n{r.stderr[-2000:]}"
     assert "HIER_OK" in r.stdout
+
+
+DECODE_SCRIPT = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.configs import reduced_config
+    from repro.dist.sharding import use_mesh
+    from repro.launch.mesh import make_host_mesh
+    from repro.models import attention as attn_mod
+    from repro.models import decode_step, init_cache, init_params
+
+    mesh = make_host_mesh(model_parallel=2)
+    assert dict(mesh.shape) == {"data": 2, "model": 2}
+    seq_sharded = attn_mod.seq_sharded
+    for name, kw, select in [("deepseek-7b", {}, True),
+                             ("deepseek-7b", {"kv_shard": "heads"}, False),
+                             ("deepseek-v2-lite-ep8", {}, True)]:
+        cfg = reduced_config(name).replace(dtype="float32", **kw)
+        params = init_params(jax.random.PRNGKey(1), cfg)
+        caches = jax.tree.map(lambda x: jax.random.normal(jax.random.PRNGKey(2), x.shape, x.dtype),
+                              init_cache(cfg, 4, 16))
+        tok = jnp.arange(4, dtype=jnp.int32)[:, None]
+        pos = jnp.asarray([0, 5, 15, 9], jnp.int32)
+        want = jax.jit(lambda p, t, c, q: decode_step(p, t, c, q, cfg))(params, tok, caches, pos)
+        seen = []
+        attn_mod.seq_sharded = lambda axes: seen.append(seq_sharded(axes)) or seen[-1]
+        with use_mesh(mesh):
+            got = jax.jit(lambda p, t, c, q: decode_step(p, t, c, q, cfg))(params, tok, caches, pos)
+        attn_mod.seq_sharded = seq_sharded
+        assert seen and all(s == select for s in seen), (name, kw, seen)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-5)
+    print("DECODE_OK")
+    """
+)
+
+
+def test_sharded_decode_selects_by_cache_layout():
+    """On a (data 2, model 2) mesh a sequence-sharded cache takes the select
+    and a head-sharded one the row write; both match the unsharded decode
+    up to float32 reduction order."""
+    env = _child_env()
+    r = subprocess.run(
+        [sys.executable, "-c", DECODE_SCRIPT], env=env, capture_output=True, text=True,
+        timeout=600, cwd=os.path.join(os.path.dirname(__file__), ".."),
+    )
+    assert r.returncode == 0, f"stdout:\n{r.stdout[-2000:]}\nstderr:\n{r.stderr[-2000:]}"
+    assert "DECODE_OK" in r.stdout
